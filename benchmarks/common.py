@@ -66,12 +66,12 @@ def bench_metadata() -> Dict[str, object]:
         "peak_rss_mb": round(peak_rss_mb(), 1),
         "cpu_time_s": round(cpu_time_s(), 2),
     }
-    try:
-        import jax
-        meta["jax"] = jax.__version__
-        meta["backend"] = jax.default_backend()
-        meta["device_count"] = jax.device_count()
-        meta["device_kind"] = jax.devices()[0].device_kind
-    except Exception as e:  # pragma: no cover - jax is baked into the image
-        meta["jax"] = f"unavailable: {e}"
+    import jax
+    from repro.kernels.ops import kernel_mode
+
+    meta["jax"] = jax.__version__
+    meta["backend"] = jax.default_backend()
+    meta["device_count"] = jax.device_count()
+    meta["device_kind"] = jax.devices()[0].device_kind
+    meta["kernel_mode"] = kernel_mode()
     return meta

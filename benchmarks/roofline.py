@@ -9,7 +9,7 @@ import json
 import os
 from typing import Dict, List
 
-from repro.launch.mesh import PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 from .common import emit
 
@@ -30,7 +30,8 @@ def roofline_fraction(rec: Dict) -> float:
     of the dominant-term roofline actually doing model FLOPs (an MFU
     upper bound for the cell)."""
     r = rec["roofline"]
-    useful_s = r["model_flops_per_chip"] / PEAK_FLOPS_BF16
+    peak = chip_peaks(rec["device_kind"])["peak_flops_bf16"]
+    useful_s = r["model_flops_per_chip"] / peak
     return useful_s / max(r["bound_step_time_s"], 1e-12)
 
 
@@ -92,8 +93,8 @@ def ideal_bytes_per_dev(rec: Dict) -> float:
 
 def bandwidth_fraction(rec: Dict) -> float:
     """MBU-style fraction: ideal minimum HBM time / bound step time."""
-    from repro.launch.mesh import HBM_BW
-    ideal_s = ideal_bytes_per_dev(rec) / HBM_BW
+    hbm_bw = chip_peaks(rec["device_kind"])["hbm_bw"]
+    ideal_s = ideal_bytes_per_dev(rec) / hbm_bw
     return ideal_s / max(rec["roofline"]["bound_step_time_s"], 1e-12)
 
 

@@ -46,6 +46,8 @@ def main() -> None:
                          "profile_report.txt (fails on >15% events/s "
                          "regression; with --quick: CI smoke)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile:
         from . import bench_profile
         print("name,us_per_call,derived")

@@ -33,7 +33,6 @@ def main():
         [FirstInFirstOut, ShortestJobFirst, LongestJobFirst, EasyBackfilling],
         [FirstFit, BestFit])
     if vectorized:
-        os.environ.setdefault("REPRO_KERNELS", "interpret")
         from repro.core.dispatchers.vectorized import (
             VectorizedAllocator, VectorizedEasyBackfilling)
         exp.add_dispatcher(FirstInFirstOut(VectorizedAllocator("FF")))

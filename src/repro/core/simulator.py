@@ -28,15 +28,6 @@ import os
 import time
 from typing import Dict, Iterable, Iterator, List, Optional, Union
 
-try:  # fast JSON if available (offline container ships orjson)
-    import orjson as _json
-
-    def _dumps(obj) -> bytes:
-        return _json.dumps(obj)
-except Exception:  # pragma: no cover
-    def _dumps(obj) -> bytes:
-        return json.dumps(obj).encode()
-
 from ..utils import rss_mb
 from .additional_data import AdditionalData, NodeFailureModel
 from .dispatchers.base import Dispatcher, SchedulerBase
@@ -46,6 +37,10 @@ from .job import Job, JobFactory, swf_resource_mapper
 from .jobtable import JobTable
 from .monitors import SystemStatus, UtilizationMonitor
 from .resources import ResourceManager
+
+
+def _dumps(obj) -> bytes:
+    return json.dumps(obj).encode()
 
 
 def default_job_factory(rm: ResourceManager) -> JobFactory:

@@ -53,6 +53,9 @@ class Experiment:
         self.sim_kwargs = sim_kwargs
         self.dispatchers: List[SchedulerBase] = []
         self.results: Dict[str, Dict] = {}
+        # FleetResult.launches of the last fleet run: one entry per
+        # cost-class launch (compile seconds, cache hit, wall, events)
+        self.fleet_launches: List[Dict] = []
 
     # ------------------------------------------------------------------
     def gen_dispatchers(self, schedulers: Sequence[Type[SchedulerBase]],
@@ -132,6 +135,7 @@ class Experiment:
                     telemetry_stride=telemetry_stride))
                 keys.append((name, rep))
         result = runner.run(sims)
+        self.fleet_launches = result.launches
 
         out: Dict[str, Dict] = {}
         for i, (name, rep) in enumerate(keys):

@@ -420,7 +420,15 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
                                   misfit),)
 
 
-def _advance_impl(s: SimState, use_kernel: bool, interpret: bool) -> SimState:
+def default_interpret() -> bool:
+    """Pallas interpret mode exactly when the backend is not a TPU."""
+    return jax.default_backend() != "tpu"
+
+
+def _advance_impl(s: SimState, use_kernel: bool,
+                  interpret: Optional[bool]) -> SimState:
+    if interpret is None:
+        interpret = default_interpret()
     m = s.submit.shape[0]
     n, r = s.avail.shape
     k_cap = s.assigned.shape[1]
@@ -733,13 +741,14 @@ def _advance_impl(s: SimState, use_kernel: bool, interpret: bool) -> SimState:
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def advance(state: SimState, use_kernel: bool = False,
-            interpret: bool = True) -> SimState:
+            interpret: Optional[bool] = None) -> SimState:
     """Run one simulation to completion on device; returns the final
-    state (all jobs COMPLETED/REJECTED, full event log)."""
+    state (all jobs COMPLETED/REJECTED, full event log).  ``interpret``
+    None resolves from the backend (:func:`default_interpret`)."""
     return _advance_impl(state, use_kernel, interpret)
 
 
-def advance_fn(use_kernel: bool = False, interpret: bool = True):
+def advance_fn(use_kernel: bool = False, interpret: Optional[bool] = None):
     """Unjitted single-sim advance closure — the unit ``FleetRunner``
     wraps in ``vmap``/``shard_map`` before jitting."""
     return lambda s: _advance_impl(s, use_kernel, interpret)

@@ -34,17 +34,13 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..utils import rss_mb
-from .engine import ALLOC_NAMES, SCHED_EBF, SCHED_NAMES, advance_fn
+from .engine import (ALLOC_NAMES, SCHED_EBF, SCHED_NAMES, advance_fn,
+                     default_interpret)
 from .state import COMPLETED, REJECTED, SimMeta, SimState, UNSET_I
 
-try:  # fast JSON if available (mirrors core.simulator)
-    import orjson as _json
 
-    def _dumps(obj) -> bytes:
-        return _json.dumps(obj)
-except Exception:  # pragma: no cover
-    def _dumps(obj) -> bytes:
-        return json.dumps(obj).encode()
+def _dumps(obj) -> bytes:
+    return json.dumps(obj).encode()
 
 
 @dataclass
@@ -283,7 +279,8 @@ class FleetRunner:
         Fuse the ``alloc_score_batch`` Pallas kernel into each dispatch
         round (one launch per round, the BatchProbe pattern).
     interpret:
-        Pallas interpret mode for the kernel; defaults to True off-TPU.
+        Pallas interpret mode for the kernel; None resolves from the
+        backend (True exactly off the TPU).
     mesh:
         A 1-D ``Mesh`` with axis ``"sims"`` (see
         :func:`repro.launch.mesh.fleet_mesh`) to shard the sim axis with
@@ -309,9 +306,8 @@ class FleetRunner:
 
         self._jax = jax
         self.use_kernel = use_kernel
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = interpret
+        self.interpret = default_interpret() if interpret is None \
+            else interpret
         self.mesh = mesh
 
     # ------------------------------------------------------------------
@@ -420,16 +416,15 @@ class FleetRunner:
         n_sims = len(padded)
         pad_sims = 0
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             n_dev = int(np.prod([d for d in mesh.devices.shape]))
             mesh_key = tuple(d.id for d in mesh.devices.flat)
             pad_sims = (-n_sims) % n_dev
-            # check_rep=False: jax has no replication rule for while_loop;
-            # every output is fully sharded on "sims" anyway
-            fn = shard_map(fn, mesh=mesh, in_specs=(P("sims"),),
-                           out_specs=P("sims"), check_rep=False)
+            # check_vma=False: every output is fully sharded on "sims",
+            # so there is no replication to track through the while_loop
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=(P("sims"),),
+                               out_specs=P("sims"), check_vma=False)
         # round the batch up to the device count with copies of the last
         # sim (dropped after the run)
         batch = list(padded) + [padded[-1]] * pad_sims

@@ -12,8 +12,10 @@ every release sharing a timestamp is applied before the fit test):
 * :func:`ebf_shadow_pallas` — the TPU kernel.  Release events are grouped
   by distinct release time into a dense delta tensor ``deltas[M, N, R]``
   (host-side, cheap: one scatter per running job).  The kernel tiles
-  nodes into VMEM blocks, computes the cumulative availability over the M
-  release prefixes and the per-prefix count of fitting nodes.
+  nodes into 128-lane VMEM blocks and walks the M release prefixes in a
+  ``fori_loop`` that carries the running availability ``[R, BN]`` and
+  writes one lane-dense fit row per prefix; the per-prefix node count
+  is a sum outside the kernel.
 * :func:`shadow_from_releases` — the host-path driver on top of it:
   groups the ``(time, nodes, vec)`` release tuples, launches the
   fit-count scan (``ops.ebf_shadow_fits``: kernel or jnp reference), and
@@ -40,20 +42,27 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_N = 256
+DEFAULT_BLOCK_N = 128
 
 # masked-minimum sentinel, same value as fleet.state.INF_I (kept local —
 # kernels must not import the fleet package)
 INF_I = 1 << 30
 
 
-def _ebf_shadow_kernel(req_ref, avail_ref, deltas_ref, fits_ref):
-    a0 = avail_ref[...]                    # [R, BN] int32
-    d = deltas_ref[...]                    # [M, R, BN] int32
-    r = req_ref[...]                       # [R, 1] int32
-    cum = a0[None, :, :] + jnp.cumsum(d, axis=0)          # [M, R, BN]
-    fit = jnp.all(cum >= r[None, :, :], axis=1)           # [M, BN]
-    fits_ref[...] = jnp.sum(fit.astype(jnp.int32), axis=1, keepdims=True)
+def _ebf_shadow_kernel(req_ref, avail_ref, deltas_ref, fit_ref):
+    # Mosaic has no cumsum: the prefix scan is a loop over the M release
+    # groups carrying the running availability [R, BN].  Prefixes index
+    # the leading (untiled) dim of deltas [M, R, BN] and fit [M, 1, BN],
+    # so every load and store is a whole (sublane, lane) tile.
+    r = req_ref[...]                                      # [R, 1]
+
+    def body(i, cur):
+        cur = cur + deltas_ref[i]                         # [R, BN]
+        fit_ref[i] = jnp.all(cur >= r, axis=0,
+                             keepdims=True).astype(jnp.int32)
+        return cur
+
+    lax.fori_loop(0, deltas_ref.shape[0], body, avail_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -68,6 +77,7 @@ def ebf_shadow_pallas(
     """Returns fits int32[M] — see ``ref.ebf_shadow_ref``."""
     m, n, r = deltas.shape
     n_pad = -(-n // block_n) * block_n
+    # padded nodes start at -1 and receive no releases: they never fit
     avail_t = jnp.full((r, n_pad), -1, dtype=jnp.int32)
     avail_t = avail_t.at[:, :n].set(avail.astype(jnp.int32).T)
     deltas_t = jnp.zeros((m, r, n_pad), dtype=jnp.int32)
@@ -75,21 +85,20 @@ def ebf_shadow_pallas(
         jnp.moveaxis(deltas.astype(jnp.int32), 2, 1))
     req2 = req.astype(jnp.int32).reshape(r, 1)
 
-    nb = n_pad // block_n
-    fits = pl.pallas_call(
+    fit = pl.pallas_call(
         _ebf_shadow_kernel,
-        grid=(nb,),
+        grid=(n_pad // block_n,),
         in_specs=[
             pl.BlockSpec((r, 1), lambda j: (0, 0)),
             pl.BlockSpec((r, block_n), lambda j: (0, j)),
             pl.BlockSpec((m, r, block_n), lambda j: (0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((m, 1), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((m, nb), jnp.int32),
+        out_specs=pl.BlockSpec((m, 1, block_n), lambda j: (0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((m, 1, n_pad), jnp.int32),
         interpret=interpret,
         name="ebf_shadow",
     )(req2, avail_t, deltas_t)
-    return fits.sum(axis=1)
+    return fit.sum(axis=(1, 2), dtype=jnp.int32)
 
 
 # ----------------------------------------------------------------------

@@ -1,10 +1,12 @@
 """Public jit'd wrappers for the kernel layer.
 
-Dispatch policy: the Pallas path runs on real TPU (``interpret=False``) or
-under forced interpretation (tests / CPU validation).  Lowering for a
-non-TPU backend — e.g. the CPU-hosted multi-pod dry-run — falls back to the
-``ref.py`` oracles, whose HLO is what XLA:TPU would see anyway for these
-memory-bound ops.  Set ``REPRO_KERNELS=interpret|ref|tpu`` to override.
+Dispatch policy: the Pallas path runs compiled on a TPU backend
+(``interpret=False``).  On any other backend — e.g. the CPU-hosted
+multi-pod dry-run — the ``ref.py`` oracles run instead, whose HLO is what
+XLA:TPU would see anyway for these memory-bound ops.
+``REPRO_KERNELS=interpret|ref|stub`` overrides that off the TPU (tests run
+the Pallas interpreter on the CPU); on a TPU backend any value but
+``tpu`` is refused, so a setting can never hide the device.
 """
 from __future__ import annotations
 
@@ -19,11 +21,18 @@ from .ebf_shadow import ebf_shadow_pallas
 from .selective_scan import selective_scan_pallas
 
 
-def _mode() -> str:
+def kernel_mode() -> str:
+    """``tpu`` (compiled Pallas), ``interpret``, ``ref`` or ``stub``."""
     forced = os.environ.get("REPRO_KERNELS")
+    if jax.default_backend() == "tpu":
+        if forced not in (None, "", "tpu"):
+            raise RuntimeError(
+                f"REPRO_KERNELS={forced} would run the kernels off the "
+                f"TPU this process holds; unset it to run them compiled")
+        return "tpu"
     if forced in ("interpret", "ref", "tpu", "stub"):
         return forced
-    return "tpu" if jax.default_backend() == "tpu" else "ref"
+    return "ref"
 
 
 # ----------------------------------------------------------------------
@@ -57,7 +66,7 @@ def _scan_traffic_stub(u, delta, A, B, C, D):
 def alloc_score(avail, capacity, req):
     """(fit int32[N], score f32[N]) for one job request (FF/BF inner loop)."""
     _record("alloc_score")
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return jax.jit(ref.alloc_score_ref)(avail, capacity, req)
     return alloc_score_pallas(avail, capacity, req,
@@ -81,7 +90,7 @@ def alloc_score_batch(avail, capacity, req):
     import numpy as np
 
     _record("alloc_score_batch")
-    mode = _mode()
+    mode = kernel_mode()
     req = np.asarray(req)
     j = req.shape[0]
     j_bucket = max(8, 1 << max(j - 1, 0).bit_length())
@@ -97,19 +106,34 @@ def alloc_score_batch(avail, capacity, req):
 
 
 def ebf_shadow_fits(avail, deltas, req):
-    """fits int32[M]: fitting-node count per release prefix (EBF shadow)."""
+    """fits int32[M]: fitting-node count per release prefix (EBF shadow).
+
+    The prefix axis is padded on the host to the next power of two (>= 8)
+    with zero deltas, which repeat the last prefix's count and are sliced
+    off: every blocked head has its own number of distinct release
+    times, and without the bucket each would compile its own kernel."""
+    import numpy as np
+
     _record("ebf_shadow")
-    mode = _mode()
+    mode = kernel_mode()
+    deltas = np.asarray(deltas)
+    m = deltas.shape[0]
+    m_bucket = max(8, 1 << max(m - 1, 0).bit_length())
+    if m_bucket != m:
+        deltas = np.concatenate([deltas, np.zeros(
+            (m_bucket - m,) + deltas.shape[1:], dtype=deltas.dtype)])
     if mode == "ref":
-        return jax.jit(ref.ebf_shadow_ref)(avail, deltas, req)
-    return ebf_shadow_pallas(avail, deltas, req,
-                             interpret=(mode == "interpret"))
+        fits = jax.jit(ref.ebf_shadow_ref)(avail, deltas, req)
+    else:
+        fits = ebf_shadow_pallas(avail, deltas, req,
+                                 interpret=(mode == "interpret"))
+    return np.asarray(fits)[:m]
 
 
 def selective_scan(u, delta, A, B, C, D, chunk: int = 128):
     """Mamba-1 selective scan: (y, h_last)."""
     _record("selective_scan")
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "stub":
         return _scan_traffic_stub(u, delta, A, B, C, D)
     if mode == "ref":

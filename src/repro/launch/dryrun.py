@@ -34,8 +34,11 @@ from ..sharding import shardings_from_axes, use_rules
 from ..training import AdamWConfig, TrainStepConfig, adamw_init, make_train_step
 from ..training.optimizer import opt_state_logical_axes
 from .hlo_analysis import analyze_hlo_text
-from .mesh import (HBM_PER_CHIP, ICI_BW_PER_LINK, PEAK_FLOPS_BF16, HBM_BW,
-                   make_production_mesh)
+from .mesh import chip_peaks, make_production_mesh
+
+# the chip the dry-run's placeholder devices stand for (its roofline
+# denominators come from ``mesh.CHIP_PEAKS``)
+TARGET_KIND = "TPU v5 lite"
 
 # microbatch defaults per shape kind (activation-memory knob; §Perf)
 DEFAULT_MICROBATCHES = {"train": 4, "prefill": 1, "decode": 1}
@@ -113,8 +116,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, rules: str,
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     chips = mesh.devices.size
+    peaks = chip_peaks(TARGET_KIND)
     rec: Dict = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
-                 "chips": int(chips), "rules": tag or rules,
+                 "device_kind": TARGET_KIND, "chips": int(chips), "rules": tag or rules,
                  "rules_base": rules, "ok": False,
                  "knobs": {"remat": remat, "state_dtype": state_dtype,
                             "scan_impl": scan_impl,
@@ -146,11 +150,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, rules: str,
                 "alias_bytes": ma.alias_size_in_bytes,
                 "per_device_bytes": int(per_dev),
                 "per_device_gib": round(per_dev / 2**30, 3),
-                "fits_16gib_hbm": bool(per_dev <= HBM_PER_CHIP),
+                "fits_16gib_hbm": bool(per_dev <= peaks["hbm_bytes"]),
             }
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-                ca = ca[0] if ca else {}
             rec["cost_analysis_raw"] = {
                 "flops": float(ca.get("flops", -1)),
                 "bytes_accessed": float(ca.get("bytes accessed", -1)),
@@ -167,9 +169,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, rules: str,
                 "collective_count": dict(cost.collective_count),
             }
             mf = model_flops(cfg, shape)
-            compute_s = cost.flops / PEAK_FLOPS_BF16
-            memory_s = cost.bytes / HBM_BW
-            coll_s = cost.total_collective_link_bytes / ICI_BW_PER_LINK
+            compute_s = cost.flops / peaks["peak_flops_bf16"]
+            memory_s = cost.bytes / peaks["hbm_bw"]
+            coll_s = (cost.total_collective_link_bytes
+                      / peaks["ici_bw_per_link"])
             dominant = max(
                 (("compute", compute_s), ("memory", memory_s),
                  ("collective", coll_s)), key=lambda kv: kv[1])[0]

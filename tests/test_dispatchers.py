@@ -1,8 +1,8 @@
-"""Property-based tests of the dispatching invariants (hypothesis when
-installed, seeded parametrization otherwise — see _hyp_compat)."""
+"""Property-based tests of the dispatching invariants."""
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import EventManager, Job, ResourceManager
 from repro.core.dispatchers import (BestFit, EasyBackfilling, FirstFit,

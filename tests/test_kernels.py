@@ -1,7 +1,8 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret) vs ref.py oracles."""
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 from repro.kernels import ref
@@ -130,6 +131,41 @@ def test_ebf_shadow_monotone():
     assert np.all(np.diff(fits) >= 0)
 
 
+@pytest.mark.parametrize("m", [1, 5, 8, 9, 33])
+def test_ebf_shadow_fits_prefix_bucket_is_inert(m):
+    """``ops.ebf_shadow_fits`` pads the prefix axis to a power of two with
+    zero deltas; the padded call must give the unpadded fits."""
+    from repro.kernels import ops
+
+    n, r = 120, 2
+    avail = RNG.integers(0, 4, (n, r)).astype(np.int32)
+    deltas = RNG.integers(0, 2, (m, n, r)).astype(np.int32)
+    req = np.array([3, 2], np.int32)
+    got = ops.ebf_shadow_fits(avail, deltas, req)
+    want = np.asarray(ebf_shadow_pallas(jnp.asarray(avail),
+                                        jnp.asarray(deltas),
+                                        jnp.asarray(req), interpret=True))
+    assert got.shape == (m,)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("forced", ["interpret", "ref", "stub", None])
+def test_kernel_mode_never_hides_the_tpu(monkeypatch, forced):
+    """On a TPU backend the kernels run compiled; a forced interpreter or
+    reference mode is refused, never silently obeyed."""
+    import jax
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if forced is None:
+        monkeypatch.delenv("REPRO_KERNELS", raising=False)
+        assert ops.kernel_mode() == "tpu"
+    else:
+        monkeypatch.setenv("REPRO_KERNELS", forced)
+        with pytest.raises(RuntimeError, match="REPRO_KERNELS"):
+            ops.kernel_mode()
+
+
 @settings(max_examples=15, deadline=None)
 @given(n=st.integers(2, 24), r=st.integers(1, 3), jobs=st.integers(0, 12),
        seed=st.integers(0, 999))
@@ -152,7 +188,7 @@ def test_shadow_walk_matches_host_scan(n, r, jobs, seed):
     req = np.zeros((m, r), np.int32)
     releases = []
     for j in range(jobs):
-        k = int(rng.integers(1, k_cap + 1))
+        k = int(rng.integers(1, min(k_cap, n) + 1))
         nodes = rng.choice(n, size=k, replace=False)
         vec = rng.integers(0, 3, r).astype(np.int32)
         t = int(rng.integers(1, 5))                 # tight range -> ties

@@ -53,8 +53,9 @@ class Experiment:
         self.sim_kwargs = sim_kwargs
         self.dispatchers: List[SchedulerBase] = []
         self.results: Dict[str, Dict] = {}
-        # FleetResult.launches of the last fleet run: one entry per
-        # cost-class launch (compile seconds, cache hit, wall, events)
+        # FleetResult.launches of the last run_simulation: one entry per
+        # cost-class launch (compile seconds, cache hit, wall, events);
+        # empty when no row ran on the fleet
         self.fleet_launches: List[Dict] = []
 
     # ------------------------------------------------------------------
@@ -184,6 +185,7 @@ class Experiment:
                        start_kwargs: Optional[Dict] = None) -> Dict[str, Dict]:
         os.makedirs(self.output_dir, exist_ok=True)
         start_kwargs = start_kwargs or {}
+        self.fleet_launches = []
 
         reasons = {s.dispatcher_name: self._fallback_reason(s, start_kwargs)
                    for s in self.dispatchers}

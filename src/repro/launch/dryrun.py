@@ -118,8 +118,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, rules: str,
     chips = mesh.devices.size
     peaks = chip_peaks(TARGET_KIND)
     rec: Dict = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
-                 "device_kind": TARGET_KIND, "chips": int(chips), "rules": tag or rules,
-                 "rules_base": rules, "ok": False,
+                 "device_kind": TARGET_KIND, "chips": int(chips),
+                 "rules": tag or rules, "rules_base": rules, "ok": False,
                  "knobs": {"remat": remat, "state_dtype": state_dtype,
                             "scan_impl": scan_impl,
                             "moe_group_size": moe_group_size}}

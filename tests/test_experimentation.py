@@ -127,13 +127,17 @@ def test_batch_planner_fleet_and_host_agree(tmp_path):
     wl = SyntheticWorkload(60, seed=40, mean_interarrival_s=30.0,
                            duration_median_s=400.0,
                            resources={"core": (1, 4), "mem": (64, 512)})
-    out = {}
+    out, launches = {}, {}
     for flag in (True, False):
         exp = Experiment(f"uf{flag}", wl, SYS, output_dir=str(tmp_path),
                          use_fleet=flag)
         exp.gen_dispatchers([ShortestJobFirst], [FirstFit])
         out[flag] = exp.run_simulation(produce_plots=False)[
             "SJF-FF"]["summaries"][0]
+        launches[flag] = exp.fleet_launches
+    # the fleet run reports its one launch; the host run launched nothing
+    assert [ln["n_sims"] for ln in launches[True]] == [1]
+    assert launches[False] == []
     assert out[True]["engine"] == "fleet"
     assert out[False]["engine"] == "host"
     for key in ("submitted", "completed", "rejected", "sim_end_time"):

@@ -91,6 +91,15 @@ def test_all_rule_sets_have_same_keys():
         assert k == base, f"rule set {name} key mismatch: {k ^ base}"
 
 
+def test_chip_peaks_by_device_kind():
+    """Roofline denominators come from a table keyed by device kind; an
+    unknown kind is refused, never given another chip's peaks."""
+    from repro.launch.mesh import chip_peaks
+    assert chip_peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="TPU v4"):
+        chip_peaks("TPU v4")
+
+
 SUBPROCESS_PROG = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Type
 from ..core.dispatchers.base import AllocatorBase, SchedulerBase
 from ..core.resources import ResourceManager
 from ..core.simulator import Simulator, default_job_factory
+from ..telemetry.spans import Span, SpanRecorder
 from ..workloads.synthetic import SyntheticWorkload
 from .plot_factory import (DECISION_PLOTS, PERFORMANCE_PLOTS,
                            TELEMETRY_PLOTS, PlotFactory)
@@ -57,6 +58,10 @@ class Experiment:
         # cost-class launch (compile seconds, cache hit, wall, events);
         # empty when no row ran on the fleet
         self.fleet_launches: List[Dict] = []
+        # host spans of the last run_simulation, in the order they opened
+        # (telemetry.SpanRecorder): experiment.run and, on the fleet path,
+        # fleet.build / fleet.launch / results.write and their children
+        self.spans: List[Span] = []
 
     # ------------------------------------------------------------------
     def gen_dispatchers(self, schedulers: Sequence[Type[SchedulerBase]],
@@ -107,7 +112,8 @@ class Experiment:
     def _rep_name(self, name: str, rep: int) -> str:
         return f"{name}-r{rep}" if self.repeats > 1 else name
 
-    def _run_fleet(self, scheds: List[SchedulerBase]) -> Dict[str, Dict]:
+    def _run_fleet(self, scheds: List[SchedulerBase],
+                   spans: SpanRecorder) -> Dict[str, Dict]:
         """Lower ``scheds`` × repeats onto ONE FleetRunner launch."""
         from ..fleet.engine import dispatch_code
         from ..fleet.runner import FleetRunner
@@ -121,7 +127,7 @@ class Experiment:
                                    "ckpt_every_s", 0) or 0)
         telemetry_stride = int(self.sim_kwargs.get("telemetry_stride", 0))
 
-        runner = FleetRunner()
+        runner = FleetRunner(spans=spans)
         sims, keys = [], []
         for sched in scheds:
             name = sched.dispatcher_name
@@ -133,7 +139,7 @@ class Experiment:
                     s_code, alloc_id=a_code, job_factory=factory,
                     seed=seed, failures=failures,
                     quarantine_s=quarantine_s, ckpt_every_s=ckpt_every_s,
-                    telemetry_stride=telemetry_stride))
+                    telemetry_stride=telemetry_stride, spans=spans))
                 keys.append((name, rep))
         result = runner.run(sims)
         self.fleet_launches = result.launches
@@ -183,15 +189,22 @@ class Experiment:
     # ------------------------------------------------------------------
     def run_simulation(self, produce_plots: bool = True,
                        start_kwargs: Optional[Dict] = None) -> Dict[str, Dict]:
-        os.makedirs(self.output_dir, exist_ok=True)
-        start_kwargs = start_kwargs or {}
         self.fleet_launches = []
+        spans = SpanRecorder()
+        self.spans = spans.spans
+        with spans.span("experiment.run", experiment=self.name):
+            return self._run_simulation(produce_plots, start_kwargs or {},
+                                        spans)
 
+    def _run_simulation(self, produce_plots: bool, start_kwargs: Dict,
+                        spans: SpanRecorder) -> Dict[str, Dict]:
+        os.makedirs(self.output_dir, exist_ok=True)
         reasons = {s.dispatcher_name: self._fallback_reason(s, start_kwargs)
                    for s in self.dispatchers}
         fleet_rows = [s for s in self.dispatchers
                       if reasons[s.dispatcher_name] is None]
-        fleet_results = self._run_fleet(fleet_rows) if fleet_rows else {}
+        fleet_results = (self._run_fleet(fleet_rows, spans) if fleet_rows
+                         else {})
 
         outputs, benches, labels = [], [], []
         for sched in self.dispatchers:       # results keep dispatcher order

@@ -62,6 +62,16 @@ either advances the submission pointer or retires >= 1 completion, so
 the loop runs at most ``2M + 8`` steps (also the event-log length and
 the runaway guard); inside a round, every trip either starts a job or
 advances the backfill cursor past one queued rank.
+
+**Phases.**  Each phase runs under a ``jax.named_scope`` (:data:`PHASES`):
+``prologue`` (the static priority order), then per event ``next_event``
+(event time and the loop test), ``complete``, ``drain`` (failures only),
+``admit``, ``dispatch`` (kernel prefilter, greedy loop), ``backfill``
+(shadow walk, head reservation, backfill loop, stats) and ``record``
+(event log, telemetry sample, counters), and ``epilogue`` after the
+loop.  A scope names the HLO ops it emits (``op_name`` metadata, the
+profiler's ``tf_op``) and changes nothing else, so a device trace
+attributes device time to phases.
 """
 from __future__ import annotations
 
@@ -83,6 +93,10 @@ SCHED_NAMES = {SCHED_FIFO: "FIFO", SCHED_SJF: "SJF", SCHED_LJF: "LJF",
 
 ALLOC_FF, ALLOC_BF = 0, 1
 ALLOC_NAMES = {ALLOC_FF: "FF", ALLOC_BF: "BF"}
+
+# the loop's named scopes, in program order
+PHASES = ("prologue", "next_event", "complete", "drain", "admit",
+          "dispatch", "backfill", "record", "epilogue")
 
 
 # ----------------------------------------------------------------------
@@ -290,46 +304,48 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
         return (state, start, end, assigned, avail,
                 n_started + oki, started_evt + oki, q_cnt, idx, go)
 
-    (state, start, end, assigned, avail, n_started, started_evt, q_cnt,
-     idx_h, _) = lax.while_loop(
-        cond, g_body,
-        (state, start, end, assigned, avail, s.n_started, jnp.int32(0),
-         q0, jnp.int32(0), q0 > 0))
+    with jax.named_scope("dispatch"):
+        (state, start, end, assigned, avail, n_started, started_evt, q_cnt,
+         idx_h, _) = lax.while_loop(
+            cond, g_body,
+            (state, start, end, assigned, avail, s.n_started, jnp.int32(0),
+             q0, jnp.int32(0), q0 > 0))
 
     # --- phase 2: EBF shadow walk + head reservation (once) -----------
     # The loop above exits with queued rows remaining exactly when its
     # last probe FAILED, so the candidate it carried out is the blocked
     # head (arbitrary when the queue drained — has_head masks that).
-    queued = state == QUEUED
-    has_head = is_ebf & (q_cnt > 0)
-    head_req = s.req[idx_h]
-    head_need = s.n_need[idx_h]
-    # estimated releases of running rows (incl. this round's starts:
-    # start == t); a job may overrun its estimate, so never before
-    # t + 1.  All-INF when no EBF head is blocked, which makes the walk
-    # a zero-trip no-op (vmap-safe).
-    rel = jnp.where((state == RUNNING) & has_head,
-                    jnp.maximum(start + s.est, t + 1), INF_I)
-    found, shadow_t, sh_avail = shadow_walk(avail, rel, assigned, s.req,
-                                            head_req, head_need,
-                                            node_ok=elig)
-    # head reservation at shadow time — shadow availability can exceed
-    # the round-start availability, so NO kernel prefilter
-    _, sel_h, _ = _select_nodes(
-        s.alloc_id, sh_avail, s.capacity, head_req, head_need, k_cap, None,
-        elig)
-    enter_bf = has_head & found
-    extra = jnp.where(
-        enter_bf,
-        sh_avail - sel_h[:, None].astype(jnp.int32) * head_req[None, :],
-        jnp.zeros_like(avail))
+    with jax.named_scope("backfill"):
+        queued = state == QUEUED
+        has_head = is_ebf & (q_cnt > 0)
+        head_req = s.req[idx_h]
+        head_need = s.n_need[idx_h]
+        # estimated releases of running rows (incl. this round's starts:
+        # start == t); a job may overrun its estimate, so never before
+        # t + 1.  All-INF when no EBF head is blocked, which makes the walk
+        # a zero-trip no-op (vmap-safe).
+        rel = jnp.where((state == RUNNING) & has_head,
+                        jnp.maximum(start + s.est, t + 1), INF_I)
+        found, shadow_t, sh_avail = shadow_walk(avail, rel, assigned, s.req,
+                                                head_req, head_need,
+                                                node_ok=elig)
+        # head reservation at shadow time — shadow availability can exceed
+        # the round-start availability, so NO kernel prefilter
+        _, sel_h, _ = _select_nodes(
+            s.alloc_id, sh_avail, s.capacity, head_req, head_need, k_cap, None,
+            elig)
+        enter_bf = has_head & found
+        extra = jnp.where(
+            enter_bf,
+            sh_avail - sel_h[:, None].astype(jnp.int32) * head_req[None, :],
+            jnp.zeros_like(avail))
 
-    # backfill pool per job: plain avail while the candidate finishes
-    # (by estimate) before the shadow time, else it must not touch the
-    # head's reservation -> min(avail, extra)
-    before_all = t + s.est <= shadow_t                           # [M]
-    cursor0 = s.fifo_rank[idx_h]
-    go0 = enter_bf & (queued & (s.fifo_rank > cursor0)).any()
+        # backfill pool per job: plain avail while the candidate finishes
+        # (by estimate) before the shadow time, else it must not touch the
+        # head's reservation -> min(avail, extra)
+        before_all = t + s.est <= shadow_t                           # [M]
+        cursor0 = s.fifo_rank[idx_h]
+        go0 = enter_bf & (queued & (s.fifo_rank > cursor0)).any()
 
     # --- phase 3: backfill behind the reservation ---------------------
     def b_body(c):
@@ -390,10 +406,11 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
         return (state, start, end, assigned, avail, extra,
                 n_started + oki, started_evt + oki, cursor, go)
 
-    out = lax.while_loop(
-        cond, b_body,
-        (state, start, end, assigned, avail, extra, n_started,
-         started_evt, cursor0, go0))
+    with jax.named_scope("backfill"):
+        out = lax.while_loop(
+            cond, b_body,
+            (state, start, end, assigned, avail, extra, n_started,
+             started_evt, cursor0, go0))
     if not collect_stats:
         return out[:5] + out[6:8]
     # phase counters, all from already-carried scalars (DESIGN.md §10).
@@ -406,16 +423,17 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
     #   backfill_admits = phase-3 starts
     #   misfit_skips    = backfill candidates behind the head that did
     #                     not start (no-fit + would-delay-head)
-    disp_trips = started_evt + (q_cnt > 0).astype(jnp.int32)
-    sh_trips = jnp.where(
-        has_head,
-        jnp.where(found,
-                  ((rel <= shadow_t) & (rel < INF_I)).sum(dtype=jnp.int32),
-                  (rel < INF_I).sum(dtype=jnp.int32)),
-        0).astype(jnp.int32)
-    bf_admits = out[7] - started_evt
-    misfit = jnp.where(has_head, (q0 - started_evt - 1) - bf_admits,
-                       0).astype(jnp.int32)
+    with jax.named_scope("backfill"):
+        disp_trips = started_evt + (q_cnt > 0).astype(jnp.int32)
+        sh_trips = jnp.where(
+            has_head,
+            jnp.where(found,
+                      ((rel <= shadow_t) & (rel < INF_I)).sum(dtype=jnp.int32),
+                      (rel < INF_I).sum(dtype=jnp.int32)),
+            0).astype(jnp.int32)
+        bf_admits = out[7] - started_evt
+        misfit = jnp.where(has_head, (q0 - started_evt - 1) - bf_admits,
+                           0).astype(jnp.int32)
     return out[:5] + out[6:8] + ((disp_trips, sh_trips, bf_admits,
                                   misfit),)
 
@@ -451,33 +469,36 @@ def _advance_impl(s: SimState, use_kernel: bool,
     # _priority_order) — one sort per sim replaces a lex argmin per
     # dispatch trip.  Requeues re-rank victims mid-run, so the order is
     # carried in the state and recomputed after each failure drain.
-    s = s._replace(pri=_priority_order(s))
+    with jax.named_scope("prologue"):
+        s = s._replace(pri=_priority_order(s))
 
     def cond(s: SimState):
-        go = (s.ptr < s.n_pending) | (s.state == RUNNING).any()
-        if has_fail:
-            # queued jobs may be waiting on a REPAIR / quarantine expiry
-            # that only a later failure event can unblock
-            queued = s.n_submitted - s.n_rejected - s.n_started
-            go = go | ((queued > 0) & (s.fptr < s.n_fail))
-        return (s.steps < guard) & go
+        with jax.named_scope("next_event"):
+            go = (s.ptr < s.n_pending) | (s.state == RUNNING).any()
+            if has_fail:
+                # queued jobs may be waiting on a REPAIR / quarantine expiry
+                # that only a later failure event can unblock
+                queued = s.n_submitted - s.n_rejected - s.n_started
+                go = go | ((queued > 0) & (s.fptr < s.n_fail))
+            return (s.steps < guard) & go
 
     def body(s: SimState) -> SimState:
         # ---- next event time: min(submission, completion, failure) ---
-        pidx = s.pending[jnp.clip(s.ptr, 0, m - 1)]
-        t_sub = jnp.where(s.ptr < s.n_pending, s.submit[pidx], INF_I)
-        running = s.state == RUNNING
-        t_end = jnp.where(running, s.end, INF_I).min()
-        t = jnp.minimum(t_sub, t_end)
-        if has_fail:
-            # a FAIL/REPAIR is a wake-up only while jobs are live
-            # (running or queued) — mirrors EventManager.next_event_time;
-            # events <= t set by a job event still drain below
-            n_live = s.n_submitted - s.n_rejected - s.n_completed
-            t_fail = jnp.where(
-                (s.fptr < s.n_fail) & (n_live > 0),
-                s.fail_ev[jnp.clip(s.fptr, 0, f_cap - 1), 0], INF_I)
-            t = jnp.minimum(t, t_fail)
+        with jax.named_scope("next_event"):
+            pidx = s.pending[jnp.clip(s.ptr, 0, m - 1)]
+            t_sub = jnp.where(s.ptr < s.n_pending, s.submit[pidx], INF_I)
+            running = s.state == RUNNING
+            t_end = jnp.where(running, s.end, INF_I).min()
+            t = jnp.minimum(t_sub, t_end)
+            if has_fail:
+                # a FAIL/REPAIR is a wake-up only while jobs are live
+                # (running or queued) — mirrors EventManager.next_event_time;
+                # events <= t set by a job event still drain below
+                n_live = s.n_submitted - s.n_rejected - s.n_completed
+                t_fail = jnp.where(
+                    (s.fptr < s.n_fail) & (n_live > 0),
+                    s.fail_ev[jnp.clip(s.fptr, 0, f_cap - 1), 0], INF_I)
+                t = jnp.minimum(t, t_fail)
 
         # ---- completions first (as advance_to), retired ONE at a time:
         # a typical event completes a single job, so an O(1)-sized inner
@@ -503,8 +524,9 @@ def _advance_impl(s: SimState, use_kernel: bool,
             return (state.at[idx].set(COMPLETED), avail + rel[:n],
                     n_completed + 1)
 
-        state, avail, n_completed = lax.while_loop(
-            c_cond, c_body, (s.state, s.avail, s.n_completed))
+        with jax.named_scope("complete"):
+            state, avail, n_completed = lax.while_loop(
+                c_cond, c_body, (s.state, s.avail, s.n_completed))
 
         # ---- failure drain: FAIL preempts + requeues, REPAIR restores -
         # (between completions and submissions, exactly advance_to's
@@ -590,29 +612,30 @@ def _advance_impl(s: SimState, use_kernel: bool,
                         quar_until, down_since, fptr + 1, n_requeued,
                         lost_work, downtime)
 
-            (state, start_f, end_f, assigned_f, avail, duration_f,
-             fifo_rank_f, rank_ctr_f, n_started_f, node_up, quar_until,
-             down_since, fptr, n_requeued, lost_work,
-             downtime) = lax.while_loop(
-                f_cond, f_body,
-                (state, s.start, s.end, s.assigned, avail, s.duration,
-                 s.fifo_rank, s.rank_ctr, s.n_started, s.node_up,
-                 s.quar_until, s.down_since, s.fptr, s.n_requeued,
-                 s.lost_work_s, s.node_downtime_s))
-            s = s._replace(
-                start=start_f, end=end_f, assigned=assigned_f,
-                duration=duration_f, fifo_rank=fifo_rank_f,
-                rank_ctr=rank_ctr_f, n_started=n_started_f,
-                node_up=node_up, quar_until=quar_until,
-                down_since=down_since, fptr=fptr, n_requeued=n_requeued,
-                lost_work_s=lost_work, node_downtime_s=downtime)
-            # requeues shifted ranks (victims re-ranked, pending rows'
-            # future ranks moved by nv) -> refresh the carried order
-            pri = _priority_order(s)
-            s = s._replace(pri=pri)
-            # dispatch-eligibility at this event: up and out of
-            # quarantine — EventManager.node_eligibility(t)
-            elig = (node_up > 0) & (quar_until <= t)
+            with jax.named_scope("drain"):
+                (state, start_f, end_f, assigned_f, avail, duration_f,
+                 fifo_rank_f, rank_ctr_f, n_started_f, node_up, quar_until,
+                 down_since, fptr, n_requeued, lost_work,
+                 downtime) = lax.while_loop(
+                    f_cond, f_body,
+                    (state, s.start, s.end, s.assigned, avail, s.duration,
+                     s.fifo_rank, s.rank_ctr, s.n_started, s.node_up,
+                     s.quar_until, s.down_since, s.fptr, s.n_requeued,
+                     s.lost_work_s, s.node_downtime_s))
+                s = s._replace(
+                    start=start_f, end=end_f, assigned=assigned_f,
+                    duration=duration_f, fifo_rank=fifo_rank_f,
+                    rank_ctr=rank_ctr_f, n_started=n_started_f,
+                    node_up=node_up, quar_until=quar_until,
+                    down_since=down_since, fptr=fptr, n_requeued=n_requeued,
+                    lost_work_s=lost_work, node_downtime_s=downtime)
+                # requeues shifted ranks (victims re-ranked, pending rows'
+                # future ranks moved by nv) -> refresh the carried order
+                pri = _priority_order(s)
+                s = s._replace(pri=pri)
+                # dispatch-eligibility at this event: up and out of
+                # quarantine — EventManager.node_eligibility(t)
+                elig = (node_up > 0) & (quar_until <= t)
 
         # ---- submission batch: contiguous pending prefix with T_sb <= t,
         # admitted one row per trip in (T_sb, seq) order — ranks are
@@ -635,107 +658,111 @@ def _advance_impl(s: SimState, use_kernel: bool,
             return (state, queued_time, fifo_rank, ptr + 1, rank_ctr + 1,
                     n_sub + 1, n_rej + unfit.astype(jnp.int32))
 
-        (state, queued_time, fifo_rank, ptr, rank_ctr, n_submitted,
-         n_rejected) = lax.while_loop(
-            s_cond, s_body,
-            (state, s.queued_time, s.fifo_rank, s.ptr, s.rank_ctr,
-             s.n_submitted, s.n_rejected))
+        with jax.named_scope("admit"):
+            (state, queued_time, fifo_rank, ptr, rank_ctr, n_submitted,
+             n_rejected) = lax.while_loop(
+                s_cond, s_body,
+                (state, s.queued_time, s.fifo_rank, s.ptr, s.rank_ctr,
+                 s.n_submitted, s.n_rejected))
 
-        s1 = s._replace(state=state, queued_time=queued_time,
-                        fifo_rank=fifo_rank)
+            s1 = s._replace(state=state, queued_time=queued_time,
+                            fifo_rank=fifo_rank)
 
         # ---- dispatch (one kernel launch per round) -------------------
         # queued count from the admit/start/complete counters (a row is
         # QUEUED iff admitted and neither rejected nor started) — saves
         # an [M] reduction per event
-        q0 = n_submitted - n_rejected - s.n_started
-        any_queued = q0 > 0
-        if use_kernel:
-            fit_round, _ = alloc_score_batch_pallas(
-                avail, s.capacity, s1.req, interpret=interpret)
-        else:
-            fit_round = None
+        with jax.named_scope("dispatch"):
+            q0 = n_submitted - n_rejected - s.n_started
+            any_queued = q0 > 0
+            if use_kernel:
+                fit_round, _ = alloc_score_batch_pallas(
+                    avail, s.capacity, s1.req, interpret=interpret)
+            else:
+                fit_round = None
         res = _dispatch_round(
             s1, state, s1.start, s1.end, s1.assigned, avail, t, fit_round,
             pri, q0, elig, collect_stats=has_tele)
         (state, start, end, assigned, avail, n_started,
          started_evt) = res[:7]
-        n_rounds = s.n_rounds + any_queued.astype(jnp.int32)
 
-        # ---- per-event log (host bench-line schema) -------------------
-        i = jnp.clip(s.n_events, 0, e - 1)
-        log_t = s.log_t.at[i].set(t)
-        log_queue = s.log_queue.at[i].set(q0 - started_evt)
-        log_running = s.log_running.at[i].set(n_started - n_completed)
-        log_started = s.log_started.at[i].set(started_evt)
+        # ---- per-event log (host bench-line schema) and counters -------
+        with jax.named_scope("record"):
+            n_rounds = s.n_rounds + any_queued.astype(jnp.int32)
+            i = jnp.clip(s.n_events, 0, e - 1)
+            log_t = s.log_t.at[i].set(t)
+            log_queue = s.log_queue.at[i].set(q0 - started_evt)
+            log_running = s.log_running.at[i].set(n_started - n_completed)
+            log_started = s.log_started.at[i].set(started_evt)
 
-        new = s._replace(
-            state=state, queued_time=queued_time, start=start, end=end,
-            fifo_rank=fifo_rank, assigned=assigned, avail=avail,
-            ptr=ptr, now=t, rank_ctr=rank_ctr,
-            n_submitted=n_submitted, n_completed=n_completed,
-            n_rejected=n_rejected, n_started=n_started,
-            n_events=s.n_events + 1, n_rounds=n_rounds,
-            steps=s.steps + 1,
-            log_t=log_t, log_queue=log_queue, log_running=log_running,
-            log_started=log_started)
+            new = s._replace(
+                state=state, queued_time=queued_time, start=start, end=end,
+                fifo_rank=fifo_rank, assigned=assigned, avail=avail,
+                ptr=ptr, now=t, rank_ctr=rank_ctr,
+                n_submitted=n_submitted, n_completed=n_completed,
+                n_rejected=n_rejected, n_started=n_started,
+                n_events=s.n_events + 1, n_rounds=n_rounds,
+                steps=s.steps + 1,
+                log_t=log_t, log_queue=log_queue, log_running=log_running,
+                log_started=log_started)
 
-        if has_tele:
-            # ---- telemetry sample + phase counters (DESIGN.md §10) ----
-            # 0-based event index % stride == 0 — the FIRST event is
-            # always recorded, matching the host monitor.  stride == 0
-            # keeps a telemetry-off sim inert inside a telemetry-on
-            # batch; a full buffer stops writing (decoded as truncated).
-            # ``s.n_requeued`` is post-failure-drain (s was rebound).
-            disp, sh, bf, mis = res[7]
-            stride = s.tele_stride
-            do = (stride > 0) & (s.tele_n < tele_cap) & \
-                (s.n_events % jnp.maximum(stride, 1) == 0)
-            row = jnp.concatenate([
-                jnp.stack([t, q0 - started_evt, n_started - n_completed,
-                           n_started + s.n_requeued, s.n_requeued]),
-                avail.sum(axis=0)]).astype(jnp.int32)
-            j = jnp.clip(s.tele_n, 0, tele_cap - 1)
-            new = new._replace(
-                tele_buf=s.tele_buf.at[j].set(
-                    jnp.where(do, row, s.tele_buf[j])),
-                tele_n=s.tele_n + do.astype(jnp.int32),
-                ct_disp_trips=s.ct_disp_trips + disp,
-                ct_shadow_trips=s.ct_shadow_trips + sh,
-                ct_backfill=s.ct_backfill + bf,
-                ct_misfit=s.ct_misfit + mis)
+            if has_tele:
+                # ---- telemetry sample + phase counters (DESIGN.md §10) ----
+                # 0-based event index % stride == 0 — the FIRST event is
+                # always recorded, matching the host monitor.  stride == 0
+                # keeps a telemetry-off sim inert inside a telemetry-on
+                # batch; a full buffer stops writing (decoded as truncated).
+                # ``s.n_requeued`` is post-failure-drain (s was rebound).
+                disp, sh, bf, mis = res[7]
+                stride = s.tele_stride
+                do = (stride > 0) & (s.tele_n < tele_cap) & \
+                    (s.n_events % jnp.maximum(stride, 1) == 0)
+                row = jnp.concatenate([
+                    jnp.stack([t, q0 - started_evt, n_started - n_completed,
+                               n_started + s.n_requeued, s.n_requeued]),
+                    avail.sum(axis=0)]).astype(jnp.int32)
+                j = jnp.clip(s.tele_n, 0, tele_cap - 1)
+                new = new._replace(
+                    tele_buf=s.tele_buf.at[j].set(
+                        jnp.where(do, row, s.tele_buf[j])),
+                    tele_n=s.tele_n + do.astype(jnp.int32),
+                    ct_disp_trips=s.ct_disp_trips + disp,
+                    ct_shadow_trips=s.ct_shadow_trips + sh,
+                    ct_backfill=s.ct_backfill + bf,
+                    ct_misfit=s.ct_misfit + mis)
         return new
 
     out = lax.while_loop(cond, body, s)
-    if has_fail:
-        # host livelock parity: queued jobs that outlast every event
-        # (submissions, completions, the failure schedule) can never
-        # start; the host simulator rejects them without another event
-        # point, so no event is counted here either
-        leftover = out.state == QUEUED
-        out = out._replace(
-            state=jnp.where(leftover, REJECTED,
-                            out.state).astype(jnp.int32),
-            n_rejected=out.n_rejected + leftover.sum(dtype=jnp.int32))
-    if has_tele:
-        # end-of-sim sample when the last event missed the stride —
-        # AFTER the livelock rejection above, exactly where the host
-        # monitor's finalize() runs, so both engines close the series
-        # on the same post-rejection counts
-        stride = out.tele_stride
-        need = (stride > 0) & (out.n_events > 0) & \
-            (out.tele_n < tele_cap) & \
-            ((out.n_events - 1) % jnp.maximum(stride, 1) != 0)
-        queue_now = out.n_submitted - out.n_rejected - out.n_started
-        row = jnp.concatenate([
-            jnp.stack([out.now, queue_now, out.n_started - out.n_completed,
-                       out.n_started + out.n_requeued, out.n_requeued]),
-            out.avail.sum(axis=0)]).astype(jnp.int32)
-        j = jnp.clip(out.tele_n, 0, tele_cap - 1)
-        out = out._replace(
-            tele_buf=out.tele_buf.at[j].set(
-                jnp.where(need, row, out.tele_buf[j])),
-            tele_n=out.tele_n + need.astype(jnp.int32))
+    with jax.named_scope("epilogue"):
+        if has_fail:
+            # host livelock parity: queued jobs that outlast every event
+            # (submissions, completions, the failure schedule) can never
+            # start; the host simulator rejects them without another event
+            # point, so no event is counted here either
+            leftover = out.state == QUEUED
+            out = out._replace(
+                state=jnp.where(leftover, REJECTED,
+                                out.state).astype(jnp.int32),
+                n_rejected=out.n_rejected + leftover.sum(dtype=jnp.int32))
+        if has_tele:
+            # end-of-sim sample when the last event missed the stride —
+            # AFTER the livelock rejection above, exactly where the host
+            # monitor's finalize() runs, so both engines close the series
+            # on the same post-rejection counts
+            stride = out.tele_stride
+            need = (stride > 0) & (out.n_events > 0) & \
+                (out.tele_n < tele_cap) & \
+                ((out.n_events - 1) % jnp.maximum(stride, 1) != 0)
+            queue_now = out.n_submitted - out.n_rejected - out.n_started
+            row = jnp.concatenate([
+                jnp.stack([out.now, queue_now, out.n_started - out.n_completed,
+                           out.n_started + out.n_requeued, out.n_requeued]),
+                out.avail.sum(axis=0)]).astype(jnp.int32)
+            j = jnp.clip(out.tele_n, 0, tele_cap - 1)
+            out = out._replace(
+                tele_buf=out.tele_buf.at[j].set(
+                    jnp.where(need, row, out.tele_buf[j])),
+                tele_n=out.tele_n + need.astype(jnp.int32))
     return out
 
 

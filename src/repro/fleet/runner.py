@@ -22,6 +22,17 @@ streams (``{name}-output.jsonl`` / ``{name}-bench.jsonl``) that the
 existing metrics/plots pipeline consumes — device wall time is amortized
 uniformly over events for the per-event ``dispatch_s`` field, since the
 compiled loop has no per-event host clock.
+
+Timing lives in the host spans of :class:`~repro.telemetry.SpanRecorder`
+(``perf_counter`` clock, each also a profiler annotation): ``fleet.build``
+(``.load``, ``.export``) per lane; ``fleet.launch`` per cost class with
+``fleet.pad``, ``fleet.compile`` (cache misses only), ``fleet.execute``,
+``fleet.fetch`` and ``fleet.unstack``; ``results.write`` per lane with
+``results.records``, ``results.jobs_file``, ``results.summary`` and
+``results.events_file``.  ``FleetResult.launches`` carries each launch's
+``wall_time_s`` (execute + fetch), ``compile_time_s`` and its phase
+seconds.  On the device, the engine's phases are named scopes
+(``fleet/engine.py``).
 """
 from __future__ import annotations
 
@@ -33,6 +44,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..telemetry.spans import SpanRecorder
 from ..utils import rss_mb
 from .engine import (ALLOC_NAMES, SCHED_EBF, SCHED_NAMES, advance_fn,
                      default_interpret)
@@ -69,6 +81,8 @@ class FleetResult:
     # per-launch telemetry when run() split the grid by dispatch cost
     # class: [{"cost_class", "n_sims", "wall_time_s", ...}, ...]
     launches: List[Dict] = field(default_factory=list)
+    # the grid's host spans; write_outputs and summary add theirs
+    spans: SpanRecorder = field(default_factory=SpanRecorder)
 
     def __len__(self) -> int:
         return len(self.sims)
@@ -77,46 +91,47 @@ class FleetResult:
     def summary(self, i: int) -> Dict[str, object]:
         """Host ``Simulator.summary``-schema summary for sim ``i``;
         wall/cpu/dispatch seconds are the batched run amortized per sim."""
-        f, sim = self.finals[i], self.sims[i]
-        n_events = int(f.n_events)
-        n_rounds = int(f.n_rounds)
-        per_sim = self.wall_time_s / max(len(self.sims), 1)
-        launches = n_rounds if self.use_kernel else 0
-        rss = rss_mb()
-        out = {
-            "dispatcher": f"{SCHED_NAMES[sim.sched_id]}-"
-                          f"{ALLOC_NAMES[sim.alloc_id]}",
-            "events": n_events,
-            "submitted": int(f.n_submitted),
-            "completed": int(f.n_completed),
-            "rejected": int(f.n_rejected),
-            "cpu_time_s": per_sim,
-            "wall_time_s": per_sim,
-            "dispatch_time_s": per_sim,
-            "kernel_launches": launches,
-            "kernel_launches_per_event": (launches / n_rounds
-                                          if n_rounds else 0.0),
-            "sim_end_time": int(f.now),
-            "mem_avg_mb": rss,
-            "mem_max_mb": rss,
-            "engine": "fleet",
-        }
-        if int(f.n_fail) > 0:
-            out["failures"] = {
-                "requeued_jobs": int(f.n_requeued),
-                "lost_work_s": int(f.lost_work_s),
-                "node_downtime_s": int(f.node_downtime_s),
+        with self.spans.span("results.summary", lane=self.sims[i].name):
+            f, sim = self.finals[i], self.sims[i]
+            n_events = int(f.n_events)
+            n_rounds = int(f.n_rounds)
+            per_sim = self.wall_time_s / max(len(self.sims), 1)
+            launches = n_rounds if self.use_kernel else 0
+            rss = rss_mb()
+            out = {
+                "dispatcher": f"{SCHED_NAMES[sim.sched_id]}-"
+                              f"{ALLOC_NAMES[sim.alloc_id]}",
+                "events": n_events,
+                "submitted": int(f.n_submitted),
+                "completed": int(f.n_completed),
+                "rejected": int(f.n_rejected),
+                "cpu_time_s": per_sim,
+                "wall_time_s": per_sim,
+                "dispatch_time_s": per_sim,
+                "kernel_launches": launches,
+                "kernel_launches_per_event": (launches / n_rounds
+                                              if n_rounds else 0.0),
+                "sim_end_time": int(f.now),
+                "mem_avg_mb": rss,
+                "mem_max_mb": rss,
+                "engine": "fleet",
             }
-        tele = self.telemetry(i)
-        if tele is not None:
-            out["telemetry"] = {
-                "stride": tele.stride,
-                "n_samples": tele.n_samples,
-                "phase_counters": dict(tele.phase_counters),
-            }
-        if sim.seed is not None:
-            out["seed"] = sim.seed
-        return out
+            if int(f.n_fail) > 0:
+                out["failures"] = {
+                    "requeued_jobs": int(f.n_requeued),
+                    "lost_work_s": int(f.lost_work_s),
+                    "node_downtime_s": int(f.node_downtime_s),
+                }
+            tele = self.telemetry(i)
+            if tele is not None:
+                out["telemetry"] = {
+                    "stride": tele.stride,
+                    "n_samples": tele.n_samples,
+                    "phase_counters": dict(tele.phase_counters),
+                }
+            if sim.seed is not None:
+                out["seed"] = sim.seed
+            return out
 
     # ------------------------------------------------------------------
     def telemetry(self, i: int):
@@ -210,17 +225,30 @@ class FleetResult:
         """Write ``{name}-output.jsonl`` and ``{name}-bench.jsonl`` for
         sim ``i`` — byte-compatible with the host simulator's streams, so
         metrics/plots consume them unchanged."""
-        os.makedirs(output_dir, exist_ok=True)
         name = self.sims[i].name
         out_path = os.path.join(output_dir, f"{name}-output.jsonl")
         bench_path = os.path.join(output_dir, f"{name}-bench.jsonl")
-        with open(out_path, "wb") as fh:
-            for rec in self.records(i):
-                fh.write(_dumps(rec) + b"\n")
+        spans = self.spans
+        with spans.span("results.write", lane=name,
+                        events=int(self.finals[i].n_events)):
+            os.makedirs(output_dir, exist_ok=True)
+            with spans.span("results.records"):
+                records = self.records(i)
+            with spans.span("results.jobs_file"):
+                with open(out_path, "wb") as fh:
+                    for rec in records:
+                        fh.write(_dumps(rec) + b"\n")
+            summ = self.summary(i)
+            with spans.span("results.events_file"):
+                self._write_events(bench_path, i, summ)
+            self.write_telemetry(output_dir, i)
+        return out_path, bench_path
 
+    def _write_events(self, bench_path: str, i: int, summ: Dict) -> None:
+        """Sim ``i``'s event log and summary line, as the host
+        simulator's ``{name}-bench.jsonl``."""
         f = self.finals[i]
         n_events = int(f.n_events)
-        summ = self.summary(i)
         dispatch_amort = summ["dispatch_time_s"] / max(n_events, 1)
         log_t = np.asarray(f.log_t)[:n_events]
         log_q = np.asarray(f.log_queue)[:n_events]
@@ -238,8 +266,6 @@ class FleetResult:
                     "rss_mb": rss,
                 }) + b"\n")
             fh.write(_dumps({"summary": summ}) + b"\n")
-        self.write_telemetry(output_dir, i)
-        return out_path, bench_path
 
     def write_telemetry(self, output_dir: str, i: int) -> Optional[str]:
         """Write sim ``i``'s ``{name}-telemetry.jsonl`` (the same
@@ -286,6 +312,10 @@ class FleetRunner:
         :func:`repro.launch.mesh.fleet_mesh`) to shard the sim axis with
         ``shard_map``; default shards automatically when more than one
         local device is present.
+    spans:
+        The :class:`~repro.telemetry.SpanRecorder` the launches and the
+        result's writing record into (``Experiment`` passes its grid's);
+        a fresh one by default.
 
     Compile caching: sims are padded to *bucketed* ``(M, K)`` shapes
     (rows to a multiple of 64, width to a power of two, failure events
@@ -301,7 +331,8 @@ class FleetRunner:
     _compile_cache: Dict[Tuple, object] = {}
 
     def __init__(self, use_kernel: bool = False,
-                 interpret: Optional[bool] = None, mesh=None) -> None:
+                 interpret: Optional[bool] = None, mesh=None,
+                 spans: Optional[SpanRecorder] = None) -> None:
         import jax
 
         self._jax = jax
@@ -309,6 +340,8 @@ class FleetRunner:
         self.interpret = default_interpret() if interpret is None \
             else interpret
         self.mesh = mesh
+        # the launches' spans; each FleetResult records into it too
+        self.spans = SpanRecorder() if spans is None else spans
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -317,18 +350,26 @@ class FleetRunner:
               seed: Optional[int] = None, failures=None,
               quarantine_s: int = 0, ckpt_every_s: int = 0,
               telemetry_stride: int = 0,
-              telemetry_samples: Optional[int] = None) -> FleetSim:
+              telemetry_samples: Optional[int] = None,
+              spans: Optional[SpanRecorder] = None) -> FleetSim:
         """Materialize one grid point from a workload.  ``failures`` /
         ``quarantine_s`` / ``ckpt_every_s`` install a device-resident
         FAIL/REPAIR schedule (``Simulator(failures=...)`` semantics).
         ``telemetry_stride`` > 0 allocates device-resident telemetry
-        buffers (DESIGN.md §10) decoded by ``FleetResult.telemetry``."""
-        state, meta = SimState.from_workload(
-            workload, sys_config, job_factory=job_factory,
-            sched_id=sched_id, alloc_id=alloc_id, failures=failures,
-            quarantine_s=quarantine_s, ckpt_every_s=ckpt_every_s,
-            telemetry_stride=telemetry_stride,
-            telemetry_samples=telemetry_samples)
+        buffers (DESIGN.md §10) decoded by ``FleetResult.telemetry``.
+        The ``fleet.build`` span and its two children go to ``spans``."""
+        spans = SpanRecorder() if spans is None else spans
+        with spans.span("fleet.build", lane=name):
+            with spans.span("fleet.build.load"):
+                em = SimState.load_event_manager(
+                    workload, sys_config, job_factory=job_factory,
+                    failures=failures, quarantine_s=quarantine_s,
+                    ckpt_every_s=ckpt_every_s)
+            with spans.span("fleet.build.export"):
+                state, meta = SimState.from_event_manager(
+                    em, sched_id=sched_id, alloc_id=alloc_id,
+                    telemetry_stride=telemetry_stride,
+                    telemetry_samples=telemetry_samples)
         return FleetSim(name=name, state=state, meta=meta,
                         sched_id=sched_id, alloc_id=alloc_id, seed=seed)
 
@@ -347,7 +388,10 @@ class FleetRunner:
         independent of its batch, pinned by tests.  Homogeneous batches
         always take the single-launch path; ``wall_time_s`` /
         ``compile_time_s`` sum over launches and ``cache_hit`` reports
-        whether *every* launch reused its executable.
+        whether *every* launch reused its executable.  Each launch runs
+        in a ``fleet.launch`` span; its entry in ``launches`` carries the
+        seconds of the span's children (``pad_s``, ``execute_s``,
+        ``fetch_s``, ``unstack_s``).
         """
         if not sims:
             raise ValueError("empty fleet")
@@ -363,33 +407,71 @@ class FleetRunner:
         cache_hit = True
         n_dev = 1
         launches: List[Dict] = []
+        spans = self.spans
         for idx in groups:
-            part, w, c, hit, nd = self._launch([sims[i] for i in idx])
+            classes = {"ebf" if sims[i].sched_id == SCHED_EBF else "blocking"
+                       for i in idx}
+            cost_class = classes.pop() if len(classes) == 1 else "mixed"
+            at = len(spans.spans)
+            with spans.span("fleet.launch", cost_class=cost_class):
+                part, w, c, hit, nd = self._launch([sims[i] for i in idx])
+            phase_s = {sp.name: sp.seconds for sp in spans.children(at)}
             for j, i in enumerate(idx):
                 finals[i] = part[j]
             wall += w
             compile_time += c
             cache_hit &= hit
             n_dev = max(n_dev, nd)
-            classes = {"ebf" if sims[i].sched_id == SCHED_EBF else "blocking"
-                       for i in idx}
             launches.append({
-                "cost_class": classes.pop() if len(classes) == 1 else "mixed",
+                "cost_class": cost_class,
                 "n_sims": len(idx),
                 "events": sum(int(part[j].n_events) for j in range(len(idx))),
                 "wall_time_s": round(w, 6),
                 "compile_time_s": round(c, 6),
                 "cache_hit": hit,
+                **{f"{ph}_s": round(phase_s.get(f"fleet.{ph}", 0.0), 6)
+                   for ph in ("pad", "execute", "fetch", "unstack")},
             })
         return FleetResult(sims=list(sims), finals=finals,
                            wall_time_s=wall, compile_time_s=compile_time,
                            use_kernel=self.use_kernel, n_devices=n_dev,
-                           cache_hit=cache_hit, launches=launches)
+                           cache_hit=cache_hit, launches=launches,
+                           spans=spans)
 
     # ------------------------------------------------------------------
     def _launch(self, sims: Sequence[FleetSim]):
         """One padded/stacked/compiled launch of a homogeneous-cost batch;
-        returns ``(finals, wall_s, compile_s, cache_hit, n_devices)``."""
+        returns ``(finals, wall_s, compile_s, cache_hit, n_devices)``.
+        ``wall_s`` runs from the call to the end of the copy back to the
+        host (``fleet.execute`` + ``fleet.fetch``)."""
+        jax = self._jax
+        spans = self.spans
+        with spans.span("fleet.pad"):
+            fn, stacked, key, n_sims, n_dev = self._pad(sims)
+        compiled = self._compile_cache.get(key)
+        cache_hit = compiled is not None
+        compile_time = 0.0
+        if compiled is None:
+            with spans.span("fleet.compile") as sp:
+                compiled = jax.jit(fn).lower(stacked).compile()
+            compile_time = sp.seconds
+            self._compile_cache[key] = compiled
+        t0 = time.perf_counter()
+        with spans.span("fleet.execute"):
+            out = jax.block_until_ready(compiled(stacked))
+        with spans.span("fleet.fetch"):
+            out = jax.tree.map(np.asarray, out)
+        wall = time.perf_counter() - t0
+
+        with spans.span("fleet.unstack"):
+            finals = [jax.tree.map(lambda x: x[i], out)
+                      for i in range(n_sims)]
+        return finals, wall, compile_time, cache_hit, n_dev
+
+    def _pad(self, sims: Sequence[FleetSim]):
+        """The batch bucketed, padded and stacked, with the function to
+        compile for it; returns ``(fn, stacked, cache_key, n_sims,
+        n_devices)``."""
         jax = self._jax
         m = _bucket_rows(max(s.state.n_rows for s in sims))
         k = _bucket_width(max(s.state.assigned.shape[1] for s in sims))
@@ -433,18 +515,4 @@ class FleetRunner:
         n, r = padded[0].avail.shape
         key = (len(batch), m, k, fev, ts, n, r, self.use_kernel,
                self.interpret, mesh_key, jax.default_backend())
-        compiled = self._compile_cache.get(key)
-        cache_hit = compiled is not None
-        compile_time = 0.0
-        if compiled is None:
-            t0 = time.time()
-            compiled = jax.jit(fn).lower(stacked).compile()
-            compile_time = time.time() - t0
-            self._compile_cache[key] = compiled
-        t0 = time.time()
-        out = compiled(stacked)
-        out = jax.tree.map(np.asarray, out)   # block + pull to host
-        wall = time.time() - t0
-
-        finals = [jax.tree.map(lambda x: x[i], out) for i in range(n_sims)]
-        return finals, wall, compile_time, cache_hit, n_dev
+        return fn, stacked, key, n_sims, n_dev

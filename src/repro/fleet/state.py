@@ -251,18 +251,39 @@ class SimState(NamedTuple):
         telemetry_stride: int = 0,
         telemetry_samples: Optional[int] = None,
     ) -> Tuple["SimState", "SimMeta"]:
-        """Load a whole workload into a fresh fixed-capacity state.
+        """Load a whole workload into a fresh fixed-capacity state:
+        :meth:`load_event_manager`, then :meth:`from_event_manager`."""
+        em = cls.load_event_manager(
+            workload, sys_config, job_factory=job_factory,
+            failures=failures, quarantine_s=quarantine_s,
+            ckpt_every_s=ckpt_every_s)
+        return cls.from_event_manager(
+            em, sched_id=sched_id, alloc_id=alloc_id, k_nodes=k_nodes,
+            capacity_rows=capacity_rows, telemetry_stride=telemetry_stride,
+            telemetry_samples=telemetry_samples)
+
+    @staticmethod
+    def load_event_manager(
+        workload: Iterable,
+        sys_config: Dict,
+        job_factory: Optional[JobFactory] = None,
+        failures=None,
+        quarantine_s: int = 0,
+        ckpt_every_s: int = 0,
+    ) -> EventManager:
+        """A fresh :class:`EventManager` holding the whole workload.
 
         Records (or pre-built ``Job`` objects) stream into a
         :class:`JobTable` in workload order — row index = load sequence —
-        then the columns are exported with the pending window sorted by
+        and every row sits in the LOADED window, so that
+        :meth:`from_event_manager` exports the pending window sorted by
         ``(T_sb, seq)``, exactly the order the host event manager's
         LOADED heap pops.
 
         ``failures`` (a ``FailureInjector`` or its ``(times, nodes,
         is_fail)`` arrays) installs the native FAIL/REPAIR schedule with
         the same semantics as ``Simulator(failures=...)``; the export
-        below carries it into the device-resident ``fail_ev`` schedule.
+        carries it into the device-resident ``fail_ev`` schedule.
         """
         rm = ResourceManager(sys_config)
         factory = job_factory or JobFactory()
@@ -294,10 +315,7 @@ class SimState(NamedTuple):
                 ckpt = CheckpointRestartPolicy(ckpt_every_s)
             em.set_failure_schedule(*arrays, checkpoint=ckpt,
                                     quarantine_s=quarantine_s)
-        return cls.from_event_manager(
-            em, sched_id=sched_id, alloc_id=alloc_id, k_nodes=k_nodes,
-            capacity_rows=capacity_rows, telemetry_stride=telemetry_stride,
-            telemetry_samples=telemetry_samples)
+        return em
 
     # ------------------------------------------------------------------
     @classmethod

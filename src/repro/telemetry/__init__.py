@@ -17,9 +17,16 @@ through a single schema:
 Parity contract (pinned by ``tests/test_telemetry.py``): same workload +
 same stride ⇒ bit-identical sample matrices and phase-counter totals
 from either engine.
+
+Where the time goes is the other half: :class:`SpanRecorder` records the
+fleet path's host spans (``experiment.run`` → ``fleet.build`` /
+``fleet.launch`` / ``results.write`` and their children), each also a
+profiler annotation, and the compiled loop names its phases with
+``jax.named_scope`` so a device trace attributes device time per phase.
 """
+from .spans import Span, SpanRecorder
 from .trace import (BASE_COLUMNS, PHASE_KEYS, TelemetryTrace,
                     telemetry_columns)
 
-__all__ = ["BASE_COLUMNS", "PHASE_KEYS", "TelemetryTrace",
-           "telemetry_columns"]
+__all__ = ["BASE_COLUMNS", "PHASE_KEYS", "Span", "SpanRecorder",
+           "TelemetryTrace", "telemetry_columns"]

@@ -6,7 +6,6 @@ quantity straight from ``/proc/self/status``.
 from __future__ import annotations
 
 import os
-import time
 
 
 def rss_mb() -> float:
@@ -42,23 +41,3 @@ def cpu_time_s() -> float:
     t = os.times()
     return float(t.user + t.system)
 
-
-class Timer:
-    """Accumulating wall-clock timer with context-manager splits."""
-
-    def __init__(self) -> None:
-        self.total = 0.0
-        self.count = 0
-        self._t0 = 0.0
-
-    def __enter__(self) -> "Timer":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.total += time.perf_counter() - self._t0
-        self.count += 1
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0

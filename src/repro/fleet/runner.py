@@ -19,7 +19,9 @@ The result object re-materializes the host contract: per-sim summaries
 with the host ``Simulator.summary`` keys, per-job output records
 (``Job.to_record`` schema), golden-trace dicts, and the two JSONL
 streams (``{name}-output.jsonl`` / ``{name}-bench.jsonl``) that the
-existing metrics/plots pipeline consumes — device wall time is amortized
+existing metrics/plots pipeline consumes — filled from each lane's columns
+by line templates, byte for byte what ``json.dumps`` of the records and
+event dicts would write; device wall time is amortized
 uniformly over events for the per-event ``dispatch_s`` field, since the
 compiled loop has no per-event host clock.
 
@@ -28,11 +30,12 @@ Timing lives in the host spans of :class:`~repro.telemetry.SpanRecorder`
 (``.load``, ``.export``) per lane; ``fleet.launch`` per cost class with
 ``fleet.pad``, ``fleet.compile`` (cache misses only), ``fleet.execute``,
 ``fleet.fetch`` and ``fleet.unstack``; ``results.write`` per lane with
-``results.records``, ``results.jobs_file``, ``results.summary`` and
-``results.events_file``.  ``FleetResult.launches`` carries each launch's
-``wall_time_s`` (execute + fetch), ``compile_time_s`` and its phase
-seconds.  On the device, the engine's phases are named scopes
-(``fleet/engine.py``).
+``results.records`` (the columns' decode), ``results.jobs_file``,
+``results.summary`` and ``results.events_file`` (each file's formatting
+and write, its ``lines`` attribute the lines written).
+``FleetResult.launches`` carries each launch's ``wall_time_s`` (execute +
+fetch), ``compile_time_s`` and its phase seconds.  On the device, the
+engine's phases are named scopes (``fleet/engine.py``).
 """
 from __future__ import annotations
 
@@ -40,6 +43,7 @@ import json
 import os
 import time
 from dataclasses import dataclass, field
+from json.encoder import encode_basestring_ascii
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -51,8 +55,58 @@ from .engine import (ALLOC_NAMES, SCHED_EBF, SCHED_NAMES, advance_fn,
 from .state import COMPLETED, REJECTED, SimMeta, SimState, UNSET_I
 
 
-def _dumps(obj) -> bytes:
-    return json.dumps(obj).encode()
+_STATE_NAMES = {COMPLETED: "COMPLETED", REJECTED: "REJECTED"}
+
+
+def _json_str(s) -> str:
+    """``json.dumps(s)``; strings skip its call overhead."""
+    return encode_basestring_ascii(s) if isinstance(s, str) \
+        else json.dumps(s)
+
+
+def _job_lines(cols: Dict[str, list], rts: Sequence[str]) -> List[str]:
+    """The lines of ``{name}-output.jsonl`` from
+    :meth:`FleetResult._job_columns`: ``json.dumps`` of each
+    :meth:`FleetResult.records` entry, keys in its order, filled into one
+    line template.  A column holds Python ints (whose ``format`` is their
+    JSON) or JSON text; the rows that did not start, and those that
+    request zero of a resource, are patched by position."""
+    req = cols["req"]
+    keys = [_json_str(rt) + ": " for rt in rts]
+    every = "{" + ", ".join(key.replace("%", "%%") + "%d"
+                            for key in keys) + "}"
+    resources = list(map(every.__mod__, zip(*req)))
+    for r in cols["partial"]:
+        resources[r] = "{" + ", ".join(
+            key + str(col[r]) for key, col in zip(keys, req) if col[r]) + "}"
+    start, end, waiting = (cols[k].copy()
+                           for k in ("start", "end", "waiting"))
+    reprs = list(map(float.__repr__, cols["slowdowns"]))
+    slowdown = list(map(reprs.__getitem__, cols["slowdown_of"]))
+    assigned = list(map(str, map(cols["assigned"].__getitem__, map(
+        slice, cols["assigned_from"], cols["assigned_to"]))))
+    for r in cols["unstarted"]:
+        start[r] = end[r] = waiting[r] = slowdown[r] = "null"
+        assigned[r] = "[]"
+    state = {st: '"%s"' % _STATE_NAMES.get(st, f"STATE{st}")
+             for st in set(cols["state"])}
+    return [f'{{"id": {jid}, "user": {user}, "submit": {submit}, '
+            f'"start": {t0}, "end": {t1}, "duration": {dur}, '
+            f'"expected_duration": {exp}, "nodes": {n}, '
+            f'"resources": {res}, "assigned": {nodes}, "waiting": {wait}, '
+            f'"slowdown": {slow}, "state": {st}}}\n'
+            for jid, user, submit, t0, t1, dur, exp, n, res, nodes, wait,
+            slow, st in zip(
+                map(_json_str, cols["id"]), cols["user"], cols["submit"],
+                start, end, cols["duration"], cols["expected"],
+                cols["nodes"], resources, assigned, waiting, slowdown,
+                map(state.__getitem__, cols["state"]))]
+
+
+def _write_text(path: str, lines: List[str]) -> None:
+    """Write ASCII ``lines`` with one call."""
+    with open(path, "wb") as fh:
+        fh.write("".join(lines).encode())
 
 
 @dataclass
@@ -223,49 +277,98 @@ class FleetResult:
     # ------------------------------------------------------------------
     def write_outputs(self, output_dir: str, i: int) -> Tuple[str, str]:
         """Write ``{name}-output.jsonl`` and ``{name}-bench.jsonl`` for
-        sim ``i`` — byte-compatible with the host simulator's streams, so
-        metrics/plots consume them unchanged."""
+        sim ``i`` — byte-identical to ``json.dumps`` of :meth:`records`
+        and of the host simulator's event dicts, so metrics/plots consume
+        them unchanged.  Each file is one string filled from line
+        templates and one ``write``; the ``lines`` attribute of
+        ``results.jobs_file`` / ``results.events_file`` counts its lines."""
         name = self.sims[i].name
         out_path = os.path.join(output_dir, f"{name}-output.jsonl")
         bench_path = os.path.join(output_dir, f"{name}-bench.jsonl")
         spans = self.spans
-        with spans.span("results.write", lane=name,
-                        events=int(self.finals[i].n_events)):
+        n_events = int(self.finals[i].n_events)
+        with spans.span("results.write", lane=name, events=n_events):
             os.makedirs(output_dir, exist_ok=True)
             with spans.span("results.records"):
-                records = self.records(i)
-            with spans.span("results.jobs_file"):
-                with open(out_path, "wb") as fh:
-                    for rec in records:
-                        fh.write(_dumps(rec) + b"\n")
+                cols = self._job_columns(i)
+            with spans.span("results.jobs_file", lines=len(cols["id"])):
+                _write_text(out_path, _job_lines(
+                    cols, self.sims[i].meta.resource_types))
             summ = self.summary(i)
-            with spans.span("results.events_file"):
+            with spans.span("results.events_file", lines=n_events + 1):
                 self._write_events(bench_path, i, summ)
             self.write_telemetry(output_dir, i)
         return out_path, bench_path
 
+    def _job_columns(self, i: int) -> Dict[str, list]:
+        """Sim ``i``'s live jobs as flat host columns in row order — the
+        fields of :meth:`records` as Python ints and floats, before JSON;
+        ``req`` one column per resource type — with the positions of the
+        jobs that did not start (``unstarted``) and of those that request
+        zero of some resource (``partial``)."""
+        f, meta = self.finals[i], self.sims[i].meta
+        rows = np.flatnonzero([jid is not None for jid in meta.ids])
+        state = np.asarray(f.state)[rows]
+        start = np.asarray(f.start)[rows].astype(np.int64)
+        submit = np.asarray(f.submit)[rows].astype(np.int64)
+        duration = np.asarray(f.duration)[rows].astype(np.int64)
+        n_need = np.asarray(f.n_need)[rows]
+        req = np.asarray(f.req)[rows]
+        assigned = np.asarray(f.assigned)[rows]
+        width = assigned.shape[1]
+        started = (state == COMPLETED) & (start != UNSET_I)
+        waiting = start - submit
+        run = np.maximum(duration, 1)
+        slowdowns, slowdown_of = np.unique((waiting + run) / run,
+                                           return_inverse=True)
+        # flat columns only: a list per row would be a container per job
+        # for the garbage collector to track
+        return {
+            "id": [meta.ids[r] for r in rows.tolist()],
+            "user": meta.user[rows].tolist(),
+            "submit": submit.tolist(),
+            "start": start.tolist(),
+            "end": np.asarray(f.end)[rows].tolist(),
+            "duration": duration.tolist(),
+            "expected": meta.expected[rows].tolist(),
+            "nodes": n_need.tolist(),
+            "req": [col.tolist() for col in req.T],
+            # row r's nodes are assigned[assigned_from[r]:assigned_to[r]]
+            "assigned": assigned.ravel().tolist(),
+            "assigned_from": (np.arange(len(rows)) * width).tolist(),
+            "assigned_to": (np.arange(len(rows)) * width
+                            + np.minimum(n_need, width)).tolist(),
+            "waiting": waiting.tolist(),
+            # float64 division of exact ints is Python's int / int; each
+            # distinct slowdown is printed once
+            "slowdowns": slowdowns.tolist(),
+            "slowdown_of": slowdown_of.reshape(-1).tolist(),
+            "state": state.tolist(),
+            "unstarted": np.flatnonzero(~started).tolist(),
+            "partial": np.flatnonzero((req == 0).any(axis=1)).tolist(),
+        }
+
     def _write_events(self, bench_path: str, i: int, summ: Dict) -> None:
         """Sim ``i``'s event log and summary line, as the host
-        simulator's ``{name}-bench.jsonl``."""
+        simulator's ``{name}-bench.jsonl``.  Only ``t``, ``queue`` and
+        ``running`` vary per line; the rest is one of two tails, ``json.dumps``
+        text formatted once, the second for kernel launches (``use_kernel``
+        and a queue >= 0)."""
         f = self.finals[i]
         n_events = int(f.n_events)
         dispatch_amort = summ["dispatch_time_s"] / max(n_events, 1)
-        log_t = np.asarray(f.log_t)[:n_events]
-        log_q = np.asarray(f.log_queue)[:n_events]
-        log_r = np.asarray(f.log_running)[:n_events]
         rss = rss_mb()
-        with open(bench_path, "wb") as fh:
-            for e in range(n_events):
-                fh.write(_dumps({
-                    "t": int(log_t[e]),
-                    "queue": int(log_q[e]),
-                    "running": int(log_r[e]),
-                    "dispatch_s": dispatch_amort,
-                    "kernel_launches": 1 if (self.use_kernel and log_q[e] >= 0)
-                                       else 0,
-                    "rss_mb": rss,
-                }) + b"\n")
-            fh.write(_dumps({"summary": summ}) + b"\n")
+        tails = [json.dumps({"dispatch_s": dispatch_amort,
+                             "kernel_launches": k, "rss_mb": rss})[1:]
+                 for k in (0, int(self.use_kernel))]
+        queue = np.asarray(f.log_queue)[:n_events]
+        lines = [f'{{"t": {t}, "queue": {q}, "running": {r}, {tail}\n'
+                 for t, q, r, tail in zip(
+                     np.asarray(f.log_t)[:n_events].tolist(), queue.tolist(),
+                     np.asarray(f.log_running)[:n_events].tolist(),
+                     map(tails.__getitem__, (queue >= 0).tolist()))]
+        lines.append(json.dumps({"summary": summ}) + "\n")
+        _write_text(bench_path, lines)
 
     def write_telemetry(self, output_dir: str, i: int) -> Optional[str]:
         """Write sim ``i``'s ``{name}-telemetry.jsonl`` (the same

@@ -5,7 +5,8 @@
   ``experiment.run``, ``fleet.build`` with its two children per lane,
   ``fleet.launch`` with pad / execute / fetch / unstack per cost class
   (``fleet.compile`` only on a cache miss), ``results.write`` with its
-  four children per lane — every child inside its parent;
+  four children per lane, the two files' spans counting their lines —
+  every child inside its parent;
 * the compiled loop names its phases in the ops' ``op_name`` metadata.
 """
 import re
@@ -149,8 +150,13 @@ def test_experiment_span_tree(grids, which):
     writes = [s for s in top if s.name == "results.write"]
     assert {s.attrs["lane"] for s in writes} == lanes and len(writes) == 4
     for w in writes:
-        assert [c.name for c in _kids(spans, w)] == \
+        kids = _kids(spans, w)
+        assert [c.name for c in kids] == \
             WRITE_CHILDREN
+        # one line per job; one per event and the summary line
+        jobs_file, events_file = kids[1], kids[3]
+        assert jobs_file.attrs["lines"] == N_JOBS
+        assert events_file.attrs["lines"] == w.attrs["events"] + 1
     # the summary _run_fleet reads after writing is a child of the run
     assert sum(s.name == "results.summary" for s in top) == 4
 

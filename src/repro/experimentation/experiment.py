@@ -16,8 +16,9 @@ existing results/plots pipeline unchanged.  Everything else
 to the host engine per-dispatcher.  Fallbacks are never silent: every
 summary row carries ``engine`` ("fleet"/"host") and
 ``fallback_reason`` (None on the fleet path; on the host path, WHY the
-row could not compile — e.g. ``"non-compilable-dispatcher"`` or
-``"custom-start-kwargs"``).
+row could not compile — e.g. ``"non-compilable-dispatcher"``,
+``"custom-start-kwargs"``, or ``"bf-key-too-large"`` for a Best-Fit row on
+a machine whose Best-Fit rank table is above its limit, DESIGN.md §8).
 
 Repeat seeding: a ``SyntheticWorkload`` repeat ``rep`` runs on
 ``base_seed + rep`` (``SyntheticWorkload.reseed``), so repeats draw
@@ -104,9 +105,15 @@ class Experiment:
                                         "quarantine_s", "telemetry_stride"}
         if extra:
             return "host-only-sim-kwargs:" + ",".join(sorted(extra))
-        from ..fleet.engine import compiles
-        if not compiles(sched):
+        from ..fleet.engine import ALLOC_BF, dispatch_code
+        from ..fleet.state import BF_KEY_MAX_ENTRIES, bf_key_entries
+        codes = dispatch_code(sched)
+        if codes is None:
             return "non-compilable-dispatcher"
+        if codes[1] == ALLOC_BF and bf_key_entries(ResourceManager(
+                self.sys_config).capacity) > BF_KEY_MAX_ENTRIES:
+            # Best-Fit's rank table would be too large (DESIGN.md §8)
+            return "bf-key-too-large"
         return None
 
     def _rep_name(self, name: str, rep: int) -> str:

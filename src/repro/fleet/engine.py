@@ -35,17 +35,24 @@ reservation.  Skips don't end the backfill phase; the cursor strictly
 advances, bounding the round.
 
 **Allocation.**  FirstFit picks the first ``n_need`` fitting nodes by
-node id via a cumsum-and-scatter (no dynamic-size ``nonzero``): ``sel =
-fit & (cumsum <= need)`` marks them, ``slot = cumsum - 1`` scatters node
-ids into a ``[K+1]`` buffer whose last ("trash") entry absorbs the
-unselected writes.  BestFit runs the same cumsum-scatter over the nodes
-*re-ordered busiest-first*: a per-node leftover-capacity score
-``load = Σ_r (cap - avail)/cap`` (float32 — the exact arithmetic of the
-``alloc_score`` kernels, pinned trace-equal to the host's float64) and a
-stable ``argsort(-load)`` (ties by node id, as ``np.argsort(...,
-kind="stable")``), so each admitted job lands on its tightest-fitting
-nodes and the assignment list order matches the host's busiest-first
-output.
+node id, BestFit the first ``n_need`` *busiest-first*, through one
+shared probe (no dynamic-size ``nonzero``): a stable sort of (key, node
+id, fit) puts the nodes in the policy's order, ``sel = fit & (cumsum <=
+need)`` marks the chosen ones, and slot ``j`` of the ``[K]`` assignment
+list is the chosen node whose running count is ``j + 1`` (a one-hot min;
+the node mask is read back from the slots by a ``[N, K]`` compare, as a
+scatter or gather of node ids runs element by element on TPU).
+FirstFit's key is 0, so the stable sort keeps node-id order.  BestFit's
+key is the negated load ``Σ_r (cap - avail)/cap``, as the int32 rank of
+its float64 value, gathered from the machine's
+:class:`~repro.fleet.state.BestFitKey` at the node's usage vector (equal
+float64 loads, equal ranks: float64 splits some loads that are equal as
+fractions, and neither float32 nor exact arithmetic orders those as the
+host does); ties go by node id, as ``np.argsort(..., kind="stable")``,
+so each admitted job lands on its tightest-fitting nodes and the
+assignment list order matches the host's busiest-first output.  The
+probe runs under the ``select_nodes`` scope, inside ``dispatch`` or
+``backfill``.
 
 The fused score+commit step optionally *reuses the ``alloc_score_batch``
 Pallas kernel* (``use_kernel=True``): one ``[M, N]`` fit/score launch per
@@ -80,12 +87,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..kernels.alloc_score import alloc_score_batch_pallas
 from ..kernels.ebf_shadow import shadow_walk
-from .state import (COMPLETED, INF_I, QUEUED, REJECTED, RUNNING, SimState,
-                    UNSET_I)
+from .state import (COMPLETED, INF_I, QUEUED, REJECTED, RUNNING, BestFitKey,
+                    SimState, UNSET_I, launch_bf_key)
 
 SCHED_FIFO, SCHED_SJF, SCHED_LJF, SCHED_EBF = 0, 1, 2, 3
 SCHED_NAMES = {SCHED_FIFO: "FIFO", SCHED_SJF: "SJF", SCHED_LJF: "LJF",
@@ -194,13 +202,14 @@ def _priority_order(s: SimState):
          lambda: rank])                      # EBF runs FIFO priority
 
 
-def _select_nodes(alloc_id, pool, capacity, reqv, need, k_cap, pref,
+def _select_nodes(alloc_id, pool, capacity, bf, reqv, need, k_cap, pref,
                   elig=None):
     """Allocator probe against ``pool`` availability: FirstFit (node-id
-    order) or BestFit (busiest-first stable order) via one shared
-    cumsum-and-scatter over the policy's node ordering.
+    order) or BestFit (busiest-first stable order) via one shared sort
+    and cumsum over the policy's node ordering.
 
-    Returns ``(ok, sel [N] bool, nodes [K])``; ``pref`` optionally ANDs
+    Returns ``(ok, sel [N] bool, nodes [K])``; ``bf`` is the machine's
+    :class:`~repro.fleet.state.BestFitKey`; ``pref`` optionally ANDs
     a precomputed fit prefilter (the per-round kernel launch) into the
     live fit mask; ``elig`` (bool[N], optional) ANDs the failure-aware
     node-eligibility mask — the compiled twin of the host's -1
@@ -213,26 +222,31 @@ def _select_nodes(alloc_id, pool, capacity, reqv, need, k_cap, pref,
         fitn = fitn & pref
     if elig is not None:
         fitn = fitn & elig
-    # BestFit key: fraction-in-use summed over resource types, float32 —
-    # identical arithmetic to kernels/ref.alloc_score*, whose ordering is
-    # pinned trace-equal to the host's float64 np.argsort
-    cap = jnp.maximum(capacity, 1).astype(jnp.float32)
-    load = ((capacity - pool).astype(jnp.float32) / cap).sum(axis=1)
-    order = jnp.where(alloc_id == ALLOC_BF,
-                      jnp.argsort(-load, stable=True).astype(jnp.int32),
-                      node_ids)
-    fit_o = fitn[order]
-    csum = jnp.cumsum(fit_o.astype(jnp.int32))
-    ok = csum[-1] >= need
-    sel_o = fit_o & (csum <= need)          # first `need` fitting in order
-    slots = jnp.where(sel_o, csum - 1, k_cap)
-    nodes = jnp.full(k_cap + 1, n, jnp.int32).at[slots].set(order)[:k_cap]
-    sel = jnp.zeros(n, dtype=bool).at[order].set(sel_o)
+    with jax.named_scope("select_nodes"):
+        # BestFit key: the rank of the node's float64 load, fraction-in-use
+        # summed over resource types, looked up at its usage vector — the
+        # host's np.argsort order, float64 ties and all (DESIGN.md §8).
+        # FirstFit's key is constant, so the stable sort keeps node order
+        used = ((capacity - pool) * bf.stride).sum(axis=1)
+        key = jnp.where(alloc_id == ALLOC_BF, -bf.rank[bf.base + used], 0)
+        _, order, fit_o = lax.sort((key, node_ids, fitn.astype(jnp.int32)),
+                                   num_keys=1, is_stable=True)
+        csum = jnp.cumsum(fit_o)
+        ok = csum[-1] >= need
+        sel_o = (fit_o > 0) & (csum <= need)  # first `need` fitting in order
+        # slot j holds the selected node whose running count is j + 1; the
+        # node mask is read back from the slots: one-hot compares, where a
+        # scatter or gather of the node ids runs element by element on TPU
+        slot = jnp.arange(1, k_cap + 1, dtype=jnp.int32)
+        hit = sel_o[None, :] & (csum[None, :] == slot[:, None])
+        nodes = jnp.where(hit, order[None, :], n).min(axis=1)
+        sel = (node_ids[:, None] == nodes[None, :]).any(axis=1)
     return ok, sel, nodes
 
 
-def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
-                    fit_round, pri, q0, elig=None, collect_stats=False):
+def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
+                    avail, t, fit_round, pri, q0, elig=None,
+                    collect_stats=False):
     """One full dispatch round at event time ``t``, in three phases.
 
     **Greedy loop** — select the highest-priority queued job, probe the
@@ -261,10 +275,11 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
     pays for the widest lane's body.  ``pri`` is the static priority
     order from :func:`_priority_order`; ``q0`` the number of queued
     rows at round entry (the round never re-queues, so the count just
-    decrements per start).  Returns the updated job/node arrays and the
-    number of jobs started this event.  ``elig`` (bool[N] or None) is
-    the failure-aware node-eligibility mask, threaded through every
-    allocator probe, both bulk fit counts, and the shadow walk.
+    decrements per start); ``bf`` the machine's Best-Fit key.  Returns the
+    updated job/node arrays and the number of jobs started this event.
+    ``elig`` (bool[N] or None) is the failure-aware node-eligibility
+    mask, threaded through every allocator probe, both bulk fit counts,
+    and the shadow walk.
 
     ``collect_stats`` (STATIC — telemetry-off compiles it away) appends
     the per-event phase counters ``(dispatch_trips, shadow_trips,
@@ -290,7 +305,7 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
         need = s.n_need[idx]
         pref = None if fit_round is None else fit_round[idx] > 0
         ok_fit, sel, nodes = _select_nodes(
-            s.alloc_id, avail, s.capacity, reqv, need, k_cap, pref, elig)
+            s.alloc_id, avail, s.capacity, bf, reqv, need, k_cap, pref, elig)
         ok = has_cand & ok_fit
         dec = sel[:, None].astype(jnp.int32) * reqv[None, :]
         avail = jnp.where(ok, avail - dec, avail)
@@ -332,8 +347,8 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
         # head reservation at shadow time — shadow availability can exceed
         # the round-start availability, so NO kernel prefilter
         _, sel_h, _ = _select_nodes(
-            s.alloc_id, sh_avail, s.capacity, head_req, head_need, k_cap, None,
-            elig)
+            s.alloc_id, sh_avail, s.capacity, bf, head_req, head_need, k_cap,
+            None, elig)
         enter_bf = has_head & found
         extra = jnp.where(
             enter_bf,
@@ -383,7 +398,7 @@ def _dispatch_round(s: SimState, state, start, end, assigned, avail, t,
         # constraint — the AND is a consistency fusion
         pref = None if fit_round is None else fit_round[idx] > 0
         ok_fit, sel, nodes = _select_nodes(
-            s.alloc_id, pool, s.capacity, reqv, need, k_cap, pref, elig)
+            s.alloc_id, pool, s.capacity, bf, reqv, need, k_cap, pref, elig)
         ok = has_cand & ok_fit
 
         dec = sel[:, None].astype(jnp.int32) * reqv[None, :]
@@ -443,7 +458,7 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _advance_impl(s: SimState, use_kernel: bool,
+def _advance_impl(s: SimState, bf: BestFitKey, use_kernel: bool,
                   interpret: Optional[bool]) -> SimState:
     if interpret is None:
         interpret = default_interpret()
@@ -681,7 +696,7 @@ def _advance_impl(s: SimState, use_kernel: bool,
             else:
                 fit_round = None
         res = _dispatch_round(
-            s1, state, s1.start, s1.end, s1.assigned, avail, t, fit_round,
+            s1, bf, state, s1.start, s1.end, s1.assigned, avail, t, fit_round,
             pri, q0, elig, collect_stats=has_tele)
         (state, start, end, assigned, avail, n_started,
          started_evt) = res[:7]
@@ -713,7 +728,7 @@ def _advance_impl(s: SimState, use_kernel: bool,
                 # keeps a telemetry-off sim inert inside a telemetry-on
                 # batch; a full buffer stops writing (decoded as truncated).
                 # ``s.n_requeued`` is post-failure-drain (s was rebound).
-                disp, sh, bf, mis = res[7]
+                disp, sh, admits, mis = res[7]
                 stride = s.tele_stride
                 do = (stride > 0) & (s.tele_n < tele_cap) & \
                     (s.n_events % jnp.maximum(stride, 1) == 0)
@@ -728,7 +743,7 @@ def _advance_impl(s: SimState, use_kernel: bool,
                     tele_n=s.tele_n + do.astype(jnp.int32),
                     ct_disp_trips=s.ct_disp_trips + disp,
                     ct_shadow_trips=s.ct_shadow_trips + sh,
-                    ct_backfill=s.ct_backfill + bf,
+                    ct_backfill=s.ct_backfill + admits,
                     ct_misfit=s.ct_misfit + mis)
         return new
 
@@ -767,15 +782,25 @@ def _advance_impl(s: SimState, use_kernel: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+def _advance_jit(state: SimState, bf: BestFitKey, use_kernel: bool,
+                 interpret: Optional[bool]) -> SimState:
+    return _advance_impl(state, bf, use_kernel, interpret)
+
+
 def advance(state: SimState, use_kernel: bool = False,
             interpret: Optional[bool] = None) -> SimState:
     """Run one simulation to completion on device; returns the final
-    state (all jobs COMPLETED/REJECTED, full event log).  ``interpret``
-    None resolves from the backend (:func:`default_interpret`)."""
-    return _advance_impl(state, use_kernel, interpret)
+    state (all jobs COMPLETED/REJECTED, full event log), with the
+    machine's Best-Fit key (:func:`~repro.fleet.state.launch_bf_key`).
+    ``interpret`` None resolves from the backend
+    (:func:`default_interpret`)."""
+    bf = launch_bf_key(np.asarray(state.capacity),
+                       int(state.alloc_id) == ALLOC_BF)
+    return _advance_jit(state, bf, use_kernel, interpret)
 
 
 def advance_fn(use_kernel: bool = False, interpret: Optional[bool] = None):
-    """Unjitted single-sim advance closure — the unit ``FleetRunner``
-    wraps in ``vmap``/``shard_map`` before jitting."""
-    return lambda s: _advance_impl(s, use_kernel, interpret)
+    """Unjitted single-sim advance closure ``(state, bf_key) -> state`` —
+    the unit ``FleetRunner`` wraps in ``vmap`` (over the state only) and
+    ``shard_map`` before jitting."""
+    return lambda s, bf: _advance_impl(s, bf, use_kernel, interpret)

@@ -27,12 +27,13 @@ compiled loop has no per-event host clock.
 
 Timing lives in the host spans of :class:`~repro.telemetry.SpanRecorder`
 (``perf_counter`` clock, each also a profiler annotation): ``fleet.build``
-(``.load``, ``.export``) per lane; ``fleet.launch`` per cost class with
-``fleet.pad``, ``fleet.compile`` (cache misses only), ``fleet.execute``,
-``fleet.fetch`` and ``fleet.unstack``; ``results.write`` per lane with
-``results.records`` (the columns' decode), ``results.jobs_file``,
-``results.summary`` and ``results.events_file`` (each file's formatting
-and write, its ``lines`` attribute the lines written).
+(``.load``, ``.export``, ``.bf_key``) per lane; ``fleet.launch`` per cost
+class with ``fleet.pad``, ``fleet.compile`` (cache misses only),
+``fleet.execute``, ``fleet.fetch`` and ``fleet.unstack``;
+``results.write`` per lane with ``results.records`` (the columns'
+decode), ``results.jobs_file``, ``results.summary`` and
+``results.events_file`` (each file's formatting and write, its ``lines``
+attribute the lines written).
 ``FleetResult.launches`` carries each launch's ``wall_time_s`` (execute +
 fetch), ``compile_time_s`` and its phase seconds.  On the device, the
 engine's phases are named scopes (``fleet/engine.py``).
@@ -50,9 +51,10 @@ import numpy as np
 
 from ..telemetry.spans import SpanRecorder
 from ..utils import rss_mb
-from .engine import (ALLOC_NAMES, SCHED_EBF, SCHED_NAMES, advance_fn,
-                     default_interpret)
-from .state import COMPLETED, REJECTED, SimMeta, SimState, UNSET_I
+from .engine import (ALLOC_BF, ALLOC_NAMES, SCHED_EBF, SCHED_NAMES,
+                     advance_fn, default_interpret)
+from .state import (COMPLETED, REJECTED, SimMeta, SimState, UNSET_I,
+                    bf_key, launch_bf_key)
 
 
 _STATE_NAMES = {COMPLETED: "COMPLETED", REJECTED: "REJECTED"}
@@ -425,7 +427,8 @@ class FleetRunner:
     to a multiple of 16, telemetry sample capacity to a multiple of 64 —
     0 stays 0 in both cases so the specialized engines survive; padding
     is inert, pinned by tests), and the AOT-compiled executable is cached
-    process-wide per ``(batch, M, K, F, S, N, R, flags, devices)``, so repeated
+    process-wide per ``(batch, M, K, F, S, N, R, T, flags, devices)``
+    (``T`` the Best-Fit key's length, fixed by the machine), so repeated
     grids of the same rounded-up shape skip the jit entirely
     (``FleetResult.cache_hit``; compile time was ~2.3x the run time of a
     36-sim grid before caching).
@@ -460,7 +463,9 @@ class FleetRunner:
         FAIL/REPAIR schedule (``Simulator(failures=...)`` semantics).
         ``telemetry_stride`` > 0 allocates device-resident telemetry
         buffers (DESIGN.md §10) decoded by ``FleetResult.telemetry``.
-        The ``fleet.build`` span and its two children go to ``spans``."""
+        The ``fleet.build`` span and its three children go to ``spans``;
+        ``fleet.build.bf_key`` builds the machine's Best-Fit key or finds
+        it in the cache (None above the limit, DESIGN.md §8)."""
         spans = SpanRecorder() if spans is None else spans
         with spans.span("fleet.build", lane=name):
             with spans.span("fleet.build.load"):
@@ -473,6 +478,8 @@ class FleetRunner:
                     em, sched_id=sched_id, alloc_id=alloc_id,
                     telemetry_stride=telemetry_stride,
                     telemetry_samples=telemetry_samples)
+            with spans.span("fleet.build.bf_key"):
+                bf_key(state.capacity)      # built once, then cached
         return FleetSim(name=name, state=state, meta=meta,
                         sched_id=sched_id, alloc_id=alloc_id, seed=seed)
 
@@ -498,9 +505,12 @@ class FleetRunner:
         """
         if not sims:
             raise ValueError("empty fleet")
-        shapes = {s.state.avail.shape for s in sims}
-        if len(shapes) != 1:
-            raise ValueError(f"sims target different systems: {shapes}")
+        capacity = sims[0].state.capacity
+        if any(not np.array_equal(s.state.capacity, capacity)
+               for s in sims[1:]):
+            # one Best-Fit key serves the launch, so one machine
+            raise ValueError("sims target different systems: node "
+                             "capacities differ")
         heavy = [i for i, s in enumerate(sims) if s.sched_id == SCHED_EBF]
         light = [i for i, s in enumerate(sims) if s.sched_id != SCHED_EBF]
         groups = ([light, heavy] if group_by_cost and light and heavy
@@ -550,18 +560,18 @@ class FleetRunner:
         jax = self._jax
         spans = self.spans
         with spans.span("fleet.pad"):
-            fn, stacked, key, n_sims, n_dev = self._pad(sims)
+            fn, args, key, n_sims, n_dev = self._pad(sims)
         compiled = self._compile_cache.get(key)
         cache_hit = compiled is not None
         compile_time = 0.0
         if compiled is None:
             with spans.span("fleet.compile") as sp:
-                compiled = jax.jit(fn).lower(stacked).compile()
+                compiled = jax.jit(fn).lower(*args).compile()
             compile_time = sp.seconds
             self._compile_cache[key] = compiled
         t0 = time.perf_counter()
         with spans.span("fleet.execute"):
-            out = jax.block_until_ready(compiled(stacked))
+            out = jax.block_until_ready(compiled(*args))
         with spans.span("fleet.fetch"):
             out = jax.tree.map(np.asarray, out)
         wall = time.perf_counter() - t0
@@ -573,8 +583,9 @@ class FleetRunner:
 
     def _pad(self, sims: Sequence[FleetSim]):
         """The batch bucketed, padded and stacked, with the function to
-        compile for it; returns ``(fn, stacked, cache_key, n_sims,
-        n_devices)``."""
+        compile for it; returns ``(fn, args, cache_key, n_sims,
+        n_devices)``, ``args`` being the stacked states and the machine's
+        Best-Fit key, one copy for every lane."""
         jax = self._jax
         m = _bucket_rows(max(s.state.n_rows for s in sims))
         k = _bucket_width(max(s.state.assigned.shape[1] for s in sims))
@@ -596,8 +607,11 @@ class FleetRunner:
         if mesh is None and len(jax.devices()) > 1:
             from ..launch.mesh import fleet_mesh
             mesh = fleet_mesh()
+        bf = launch_bf_key(sims[0].state.capacity,
+                           any(s.alloc_id == ALLOC_BF for s in sims))
         fn = jax.vmap(advance_fn(use_kernel=self.use_kernel,
-                                 interpret=self.interpret))
+                                 interpret=self.interpret),
+                      in_axes=(0, None))
         n_sims = len(padded)
         pad_sims = 0
         if mesh is not None:
@@ -608,7 +622,7 @@ class FleetRunner:
             pad_sims = (-n_sims) % n_dev
             # check_vma=False: every output is fully sharded on "sims",
             # so there is no replication to track through the while_loop
-            fn = jax.shard_map(fn, mesh=mesh, in_specs=(P("sims"),),
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=(P("sims"), P()),
                                out_specs=P("sims"), check_vma=False)
         # round the batch up to the device count with copies of the last
         # sim (dropped after the run)
@@ -616,6 +630,7 @@ class FleetRunner:
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *batch)
 
         n, r = padded[0].avail.shape
-        key = (len(batch), m, k, fev, ts, n, r, self.use_kernel,
-               self.interpret, mesh_key, jax.default_backend())
-        return fn, stacked, key, n_sims, n_dev
+        key = (len(batch), m, k, fev, ts, n, r, bf.rank.shape[0],
+               self.use_kernel, self.interpret, mesh_key,
+               jax.default_backend())
+        return fn, (stacked, bf), key, n_sims, n_dev

@@ -1,7 +1,7 @@
 """Fixed-capacity simulation state — the array image of the core
 (DESIGN.md §8).
 
-Two exports live here:
+Three exports live here:
 
 * :class:`SimState` — the *compiled-loop* state: a pytree of
   fixed-capacity arrays (job columns, dense request matrix, node
@@ -13,6 +13,10 @@ Two exports live here:
   (:meth:`SimState.from_workload`) or snapshotted from a live
   :class:`~repro.core.events.EventManager` mid-simulation
   (:meth:`SimState.from_event_manager`).
+
+* :class:`BestFitKey` — Best-Fit's node order as data: the dense rank
+  of every usage vector's float64 load, and where each node's usage
+  indexes it (:func:`bf_key`).  One per machine, shared by every lane.
 
 * :class:`HostSnapshot` — the *round-trip* export: everything the host
   engine holds (JobTable columns + free list + row generations, the
@@ -452,6 +456,93 @@ class SimState(NamedTuple):
             resource_types=tuple(rm.resource_types),
             n_jobs=int(live.sum()), k_nodes=k_nodes)
         return cls(**f), meta
+
+
+# the largest Best-Fit rank table a machine may have (DESIGN.md §8); the
+# Best-Fit rows of a machine above it run on the host
+BF_KEY_MAX_ENTRIES = 1 << 24
+
+
+class BestFitKey(NamedTuple):
+    """Best-Fit's busiest-first node order as integer data (DESIGN.md §8).
+
+    The host orders nodes by the float64 load ``Σ_r used/max(cap, 1)``.
+    ``rank`` holds, for every usage vector of every distinct capacity
+    vector of the machine, the dense rank of that load over the union of
+    all of them: equal float64 loads get equal ranks, a larger load a
+    larger rank.  Node ``n`` with usage ``u`` has rank
+    ``rank[base[n] + Σ_r u[r] * stride[n, r]]``.  All int32; one copy per
+    launch, not per lane."""
+
+    rank: np.ndarray              # [T] dense rank of each usage's load
+    base: np.ndarray              # [N] offset of the node's capacity block
+    stride: np.ndarray            # [N, R] row-major strides in that block
+
+
+def bf_key_entries(capacity: np.ndarray) -> int:
+    """The length of :func:`bf_key`'s table for ``capacity`` ``[N, R]``:
+    one entry per usage vector of each distinct node capacity."""
+    groups = np.unique(np.asarray(capacity, np.int64), axis=0)
+    return sum(int(np.prod([int(c) + 1 for c in g])) for g in groups)
+
+
+_BF_KEYS: Dict[Tuple, BestFitKey] = {}
+
+
+def bf_key(capacity: np.ndarray) -> Optional[BestFitKey]:
+    """The :class:`BestFitKey` of a machine of node capacities
+    ``capacity`` ``[N, R]``, built once per distinct capacity array and
+    cached; None above :data:`BF_KEY_MAX_ENTRIES` entries.
+
+    Each load is computed as ``core/dispatchers/allocators.BestFit``
+    computes it, ``(used / np.maximum(cap, 1)).sum(axis=1)`` over int64
+    arrays, so the ranks order nodes exactly as the host's float64
+    ``argsort`` does, ties included: float64 splits some loads that are
+    equal as fractions, and the ranks split them alike."""
+    cap = np.ascontiguousarray(capacity, dtype=np.int64)
+    memo = (cap.shape, cap.tobytes())
+    if memo in _BF_KEYS:
+        return _BF_KEYS[memo]
+    if bf_key_entries(cap) > BF_KEY_MAX_ENTRIES:
+        return None
+    groups, group_of = np.unique(cap, axis=0, return_inverse=True)
+    loads, bases, strides = [], [], []
+    offset = 0
+    for g in groups:
+        dims = tuple(int(c) + 1 for c in g)
+        used = np.indices(dims).reshape(len(dims), -1).T     # row-major
+        loads.append((used / np.maximum(g, 1)).sum(axis=1))
+        bases.append(offset)
+        strides.append(np.cumprod((dims + (1,))[:0:-1])[::-1])
+        offset += used.shape[0]
+    _, rank = np.unique(np.concatenate(loads), return_inverse=True)
+    group_of = group_of.reshape(-1)
+    key = BestFitKey(
+        rank=rank.reshape(-1).astype(np.int32),
+        base=np.asarray(bases, np.int32)[group_of],
+        stride=np.asarray(strides, np.int32).reshape(
+            len(groups), -1)[group_of])
+    _BF_KEYS[memo] = key
+    return key
+
+
+def launch_bf_key(capacity: np.ndarray, best_fit: bool) -> BestFitKey:
+    """The key a launch on ``capacity`` runs with: :func:`bf_key`, or,
+    on a machine above the limit, a blank key (every node ranks 0, the
+    node-id order) where no lane is Best-Fit (``best_fit`` False).  A
+    Best-Fit lane on such a machine is a ValueError: its rows run on the
+    host (``Experiment``)."""
+    key = bf_key(capacity)
+    if key is not None:
+        return key
+    if best_fit:
+        raise ValueError(
+            f"Best-Fit key of {bf_key_entries(capacity)} entries is above "
+            f"the limit of {BF_KEY_MAX_ENTRIES} (DESIGN.md §8): run the "
+            f"Best-Fit rows of this machine on the host")
+    n, r = np.shape(capacity)
+    return BestFitKey(rank=np.zeros(1, np.int32), base=np.zeros(n, np.int32),
+                      stride=np.zeros((n, r), np.int32))
 
 
 @dataclass(frozen=True)

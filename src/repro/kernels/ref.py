@@ -19,7 +19,11 @@ def alloc_score_ref(avail: jax.Array, capacity: jax.Array, req: jax.Array):
 
     Returns (fit int32[N], score f32[N]) where fit[n] = 1 iff node n can
     host one rank of the job, and score[n] = fraction-in-use summed over
-    resource types (Best-Fit's busiest-first key, paper §3).
+    resource types (Best-Fit's busiest-first key, paper §3).  In float32
+    this score orders some nodes unlike the host's float64 loads (ties
+    that float64 splits, e.g. on 12,288 MB nodes), as the fleet engine's
+    rank key no longer does (DESIGN.md §8); only the host-vectorized
+    dispatchers read it.
     """
     fit = jnp.all(avail >= req[None, :], axis=1).astype(jnp.int32)
     cap = jnp.maximum(capacity, 1).astype(jnp.float32)
@@ -39,7 +43,9 @@ def alloc_score_batch_ref(avail: jax.Array, capacity: jax.Array,
     node n can host one rank of job j, and score[j, n] is node n's
     fraction-in-use summed over resource types (identical for all j — the
     Best-Fit key depends only on node state — but materialized [J, N] to
-    match the batched kernel's block layout).
+    match the batched kernel's block layout).  Like
+    :func:`alloc_score_ref`'s, this float32 score can order nodes unlike
+    the host's float64 loads, which the fleet engine's rank key follows.
     """
     fit = jnp.all(avail[None, :, :] >= req[:, None, :], axis=2)
     cap = jnp.maximum(capacity, 1).astype(jnp.float32)

@@ -2,12 +2,13 @@
 
 * ``SpanRecorder``: nesting, parent links, the grid id, totals;
 * an ``Experiment`` grid records the span tree of the fleet path — one
-  ``experiment.run``, ``fleet.build`` with its two children per lane,
+  ``experiment.run``, ``fleet.build`` with its three children per lane,
   ``fleet.launch`` with pad / execute / fetch / unstack per cost class
   (``fleet.compile`` only on a cache miss), ``results.write`` with its
   four children per lane, the two files' spans counting their lines —
   every child inside its parent;
-* the compiled loop names its phases in the ops' ``op_name`` metadata.
+* the compiled loop names its phases in the ops' ``op_name`` metadata,
+  and the node ordering of every allocator probe ``select_nodes``.
 """
 import re
 import time
@@ -132,7 +133,7 @@ def test_experiment_span_tree(grids, which):
     assert {s.attrs["lane"] for s in builds} == lanes and len(builds) == 4
     for b in builds:
         assert [c.name for c in _kids(spans, b)] == [
-            "fleet.build.load", "fleet.build.export"]
+            "fleet.build.load", "fleet.build.export", "fleet.build.bf_key"]
 
     launches = [s for s in top if s.name == "fleet.launch"]
     assert sorted(s.attrs["cost_class"] for s in launches) == [
@@ -208,23 +209,28 @@ def test_fleet_runner_alone_records_into_its_own_recorder():
 # ----------------------------------------------------------------------
 # named scopes on the engine's phases
 # ----------------------------------------------------------------------
-def _stacked(failures=None):
+def _lowered(failures=None):
+    """The fleet program of two FIFO-FF lanes, lowered as the runner
+    lowers it: vmapped over the states, one Best-Fit key for both."""
     sims = [FleetRunner.build(f"s{i}", _workload(seed), SYS, SCHED_FIFO,
                               failures=failures)
             for i, seed in enumerate((5, 6))]
-    fn, stacked, _, _, _ = FleetRunner()._pad(sims)
-    return stacked
+    _, args, _, _, _ = FleetRunner()._pad(sims)
+    return jax.jit(jax.vmap(advance_fn(), in_axes=(0, None))).lower(*args)
+
+
+def _scopes(text):
+    return set(re.findall(r"[\w.]+", " ".join(
+        re.findall(r'op_name="([^"]*)"', text)
+        + re.findall(r'loc\("([^"]*)"', text))))
 
 
 def _phases(text):
-    names = set(re.findall(r"[\w.]+", " ".join(
-        re.findall(r'op_name="([^"]*)"', text)
-        + re.findall(r'loc\("([^"]*)"', text))))
-    return {p for p in PHASES if p in names}
+    return {p for p in PHASES if p in _scopes(text)}
 
 
 def test_engine_phases_name_the_compiled_ops():
-    lowered = jax.jit(jax.vmap(advance_fn())).lower(_stacked())
+    lowered = _lowered()
     want = {"prologue", "next_event", "complete", "admit", "dispatch",
             "backfill", "record"}
     assert want <= _phases(lowered.as_text(debug_info=True))
@@ -232,10 +238,24 @@ def test_engine_phases_name_the_compiled_ops():
     assert "drain" not in _phases(lowered.as_text(debug_info=True))
 
 
+def test_select_nodes_scope_names_the_compiled_ops():
+    """Every allocator probe's node ordering runs under ``select_nodes``,
+    nested inside the phase that probes (the first phase on the path is
+    what a device trace charges, so the phases keep their meaning)."""
+    lowered = _lowered()
+    assert "select_nodes" in _scopes(lowered.as_text(debug_info=True))
+    paths = [p for p in re.findall(r'op_name="([^"]*)"',
+                                   lowered.compile().as_text())
+             if "select_nodes" in re.split(r"[/()]", p)]
+    assert paths
+    assert {next(t for t in re.split(r"[/()]", p) if t in PHASES)
+            for p in paths} == {"dispatch", "backfill"}
+
+
 def test_drain_scope_with_failures():
     inj = FailureInjector(10, mtbf_s=4000.0, repair_s=900.0,
                           horizon_s=6000, seed=3)
-    lowered = jax.jit(jax.vmap(advance_fn())).lower(_stacked(inj))
+    lowered = _lowered(inj)
     assert {"drain", "epilogue"} <= _phases(lowered.as_text(debug_info=True))
 
 

@@ -94,12 +94,24 @@ def lane_shapes(one_chip):
                         state)
 
 
+@pytest.fixture(scope="module")
+def bf_key_shapes(one_chip):
+    """Seth's Best-Fit key, one copy for the whole batch."""
+    from repro.configs.seth import SYSTEM
+    from repro.core.resources import ResourceManager
+    from repro.fleet.state import bf_key
+
+    key = bf_key(ResourceManager(SYSTEM).capacity)
+    return jax.tree.map(lambda x: _spec(x.shape, one_chip, x.dtype), key)
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_fleet_advance_compiles(lane_shapes, use_kernel):
+def test_fleet_advance_compiles(lane_shapes, bf_key_shapes, use_kernel):
     from repro.fleet.engine import advance_fn
 
     # interpret=False explicitly: this process's backend is the CPU, so
     # the default would resolve to the Pallas interpreter
-    fn = jax.vmap(advance_fn(use_kernel=use_kernel, interpret=False))
-    txt = _compile(fn, lane_shapes)
+    fn = jax.vmap(advance_fn(use_kernel=use_kernel, interpret=False),
+                  in_axes=(0, None))
+    txt = _compile(fn, lane_shapes, bf_key_shapes)
     assert ("tpu_custom_call" in txt) == use_kernel

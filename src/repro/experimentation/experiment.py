@@ -11,7 +11,9 @@ Grid points whose scheduler lowers onto the compiled fleet engine
 ``repro.fleet.engine.dispatch_code``) run as ONE batched ``FleetRunner``
 launch — every repeat of every compilable dispatcher advances in a
 single vmapped device call — and their summaries/outputs re-enter the
-existing results/plots pipeline unchanged.  Everything else
+existing results/plots pipeline unchanged.  Each repeat's lane state is
+built once (one ``FleetRunner.build``) and shared by its rows, which
+differ only in the scheduler and allocator codes.  Everything else
 (data-driven schedulers, runs with custom ``start_kwargs``) falls back
 to the host engine per-dispatcher.  Fallbacks are never silent: every
 summary row carries ``engine`` ("fleet"/"host") and
@@ -29,9 +31,12 @@ no seed.
 from __future__ import annotations
 
 import copy
+import dataclasses
 import json
 import os
 from typing import Dict, List, Optional, Sequence, Tuple, Type
+
+import numpy as np
 
 from ..core.dispatchers.base import AllocatorBase, SchedulerBase
 from ..core.resources import ResourceManager
@@ -121,7 +126,8 @@ class Experiment:
 
     def _run_fleet(self, scheds: List[SchedulerBase],
                    spans: SpanRecorder) -> Dict[str, Dict]:
-        """Lower ``scheds`` × repeats onto ONE FleetRunner launch."""
+        """Lower ``scheds`` × repeats onto ONE FleetRunner launch, from one
+        build per repeat."""
         from ..fleet.engine import dispatch_code
         from ..fleet.runner import FleetRunner
 
@@ -134,19 +140,33 @@ class Experiment:
                                    "ckpt_every_s", 0) or 0)
         telemetry_stride = int(self.sim_kwargs.get("telemetry_stride", 0))
 
+        # the rows of a repeat share its workload, so one build serves them
+        # all: a lane differs from the repeat's first only in the scalars
+        # sched_id / alloc_id.  The arrays are shared, not copied — nothing
+        # on the host writes a SimState in place (pad_to returns self or
+        # new arrays, _pad stacks copies)
+        codes = [dispatch_code(sched) for sched in scheds]
+        bases = []
+        for rep in range(self.repeats):
+            workload, seed = self._repeat_workload(rep)
+            bases.append(FleetRunner.build(
+                self._rep_name(scheds[0].dispatcher_name, rep), workload,
+                self.sys_config, codes[0][0], alloc_id=codes[0][1],
+                job_factory=factory, seed=seed, failures=failures,
+                quarantine_s=quarantine_s, ckpt_every_s=ckpt_every_s,
+                telemetry_stride=telemetry_stride, spans=spans,
+                lanes=len(scheds)))
+
         runner = FleetRunner(spans=spans)
         sims, keys = [], []
-        for sched in scheds:
+        for sched, (s_code, a_code) in zip(scheds, codes):
             name = sched.dispatcher_name
-            s_code, a_code = dispatch_code(sched)
-            for rep in range(self.repeats):
-                workload, seed = self._repeat_workload(rep)
-                sims.append(FleetRunner.build(
-                    self._rep_name(name, rep), workload, self.sys_config,
-                    s_code, alloc_id=a_code, job_factory=factory,
-                    seed=seed, failures=failures,
-                    quarantine_s=quarantine_s, ckpt_every_s=ckpt_every_s,
-                    telemetry_stride=telemetry_stride, spans=spans))
+            for rep, base in enumerate(bases):
+                sims.append(dataclasses.replace(
+                    base, name=self._rep_name(name, rep),
+                    state=base.state._replace(sched_id=np.int32(s_code),
+                                              alloc_id=np.int32(a_code)),
+                    sched_id=s_code, alloc_id=a_code))
                 keys.append((name, rep))
         result = runner.run(sims)
         self.fleet_launches = result.launches

@@ -27,7 +27,9 @@ compiled loop has no per-event host clock.
 
 Timing lives in the host spans of :class:`~repro.telemetry.SpanRecorder`
 (``perf_counter`` clock, each also a profiler annotation): ``fleet.build``
-(``.load``, ``.export``, ``.bf_key``) per lane; ``fleet.launch`` per cost
+(``.load``, ``.export``, ``.bf_key``) per build — in an ``Experiment`` one
+per repeat, shared by the repeat's rows, its ``lanes`` attribute the
+lanes it serves; ``fleet.launch`` per cost
 class with ``fleet.pad``, ``fleet.compile`` (cache misses only),
 ``fleet.execute``, ``fleet.fetch`` and ``fleet.unstack``;
 ``results.write`` per lane with ``results.records`` (the columns'
@@ -457,7 +459,8 @@ class FleetRunner:
               quarantine_s: int = 0, ckpt_every_s: int = 0,
               telemetry_stride: int = 0,
               telemetry_samples: Optional[int] = None,
-              spans: Optional[SpanRecorder] = None) -> FleetSim:
+              spans: Optional[SpanRecorder] = None,
+              lanes: int = 1) -> FleetSim:
         """Materialize one grid point from a workload.  ``failures`` /
         ``quarantine_s`` / ``ckpt_every_s`` install a device-resident
         FAIL/REPAIR schedule (``Simulator(failures=...)`` semantics).
@@ -465,9 +468,12 @@ class FleetRunner:
         buffers (DESIGN.md §10) decoded by ``FleetResult.telemetry``.
         The ``fleet.build`` span and its three children go to ``spans``;
         ``fleet.build.bf_key`` builds the machine's Best-Fit key or finds
-        it in the cache (None above the limit, DESIGN.md §8)."""
+        it in the cache (None above the limit, DESIGN.md §8).  ``lanes``
+        is the number of lanes the caller derives from this state (its
+        span's attribute; ``Experiment`` shares one build among a
+        repeat's rows)."""
         spans = SpanRecorder() if spans is None else spans
-        with spans.span("fleet.build", lane=name):
+        with spans.span("fleet.build", lane=name, lanes=lanes):
             with spans.span("fleet.build.load"):
                 em = SimState.load_event_manager(
                     workload, sys_config, job_factory=job_factory,

@@ -2,7 +2,8 @@
 
 * ``SpanRecorder``: nesting, parent links, the grid id, totals;
 * an ``Experiment`` grid records the span tree of the fleet path — one
-  ``experiment.run``, ``fleet.build`` with its three children per lane,
+  ``experiment.run``, ``fleet.build`` with its three children per
+  repeat (its ``lanes`` the rows that share it),
   ``fleet.launch`` with pad / execute / fetch / unstack per cost class
   (``fleet.compile`` only on a cache miss), ``results.write`` with its
   four children per lane, the two files' spans counting their lines —
@@ -129,8 +130,10 @@ def test_experiment_span_tree(grids, which):
     assert len({s.grid for s in spans}) == 1
 
     top = _kids(spans, roots[0])
+    # one build per repeat, shared by the repeat's two rows
     builds = [s for s in top if s.name == "fleet.build"]
-    assert {s.attrs["lane"] for s in builds} == lanes and len(builds) == 4
+    assert [s.attrs["lane"] for s in builds] == ["FIFO-FF-r0", "FIFO-FF-r1"]
+    assert sum(s.attrs["lanes"] for s in builds) == len(lanes)
     for b in builds:
         assert [c.name for c in _kids(spans, b)] == [
             "fleet.build.load", "fleet.build.export", "fleet.build.bf_key"]

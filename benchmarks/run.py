@@ -20,7 +20,7 @@ import time
 import traceback
 
 MODULES = ["table1", "table2", "fig_generator", "kernels", "dispatch",
-           "core", "roofline", "fleet"]
+           "core", "fleet"]
 
 
 def main() -> None:
@@ -116,9 +116,6 @@ def main() -> None:
             elif name == "core":
                 from . import bench_core
                 bench_core.run(args.out)
-            elif name == "roofline":
-                from . import roofline
-                roofline.run(args.out)
             else:
                 raise KeyError(name)
             print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)

@@ -3,7 +3,7 @@
     python3 chip_smoke.py              # one chip: the phases below
     python3 chip_smoke.py --chips 4    # four chips: the sharded grid only
 
-One chip, three phases, all checked against a reference:
+One chip, two phases, both checked against a reference:
 
 1. Main grid.  The paper's eight Table-2 dispatchers ({FIFO, SJF, LJF,
    EBF} x {FirstFit, BestFit}) x 4 seeds = 32 lanes of 2,048 Seth-like
@@ -14,10 +14,7 @@ One chip, three phases, all checked against a reference:
    completion and rejection counts, makespan and golden trace (start,
    assigned nodes and state per job) must equal the host ``Simulator``'s
    on the same workload.
-2. Kernel on.  ``FleetRunner(use_kernel=True)`` over FIFO-FF and EBF-BF:
-   the ``alloc_score_batch`` Pallas kernel compiled into every dispatch
-   round; traces must equal the kernel-off lanes of phase 1.
-3. Host path.  The vectorized host dispatchers, which launch the
+2. Host path.  The vectorized host dispatchers, which launch the
    compiled ``alloc_score_batch`` and ``ebf_shadow`` kernels from the
    host engine, against their numpy twins on the same workload.
 
@@ -134,14 +131,13 @@ def build_sims(n_jobs: int, seeds, scheds_fn):
 # ----------------------------------------------------------------------
 # phases
 # ----------------------------------------------------------------------
-def main_grid(n_jobs: int, seeds, out_dir: str, cache=None):
-    """Phase 1; returns ``{(seed, dispatcher): golden trace}``."""
+def main_grid(n_jobs: int, seeds, out_dir: str, cache=None) -> None:
+    """Phase 1."""
     from benchmarks.common import seth_jobs
     from repro.configs.seth import SYSTEM
     from repro.core.simulator import Simulator
     from repro.experimentation import Experiment
 
-    traces = {}
     for seed in seeds:
         exp = Experiment(f"seth-s{seed}", list(seth_jobs(n_jobs, seed=seed)),
                          SYSTEM, output_dir=out_dir)
@@ -171,37 +167,16 @@ def main_grid(n_jobs: int, seeds, out_dir: str, cache=None):
             label = f"{name} seed={seed}"
             for key in SUMMARY_KEYS:
                 check_equal(f"{label} {key}", summ[key], host.summary[key])
-            trace = read_trace(results[name]["output"])
-            check_equal(f"{label} trace", trace, read_trace(host_out))
-            traces[(seed, name)] = trace
+            check_equal(f"{label} trace", read_trace(results[name]["output"]),
+                        read_trace(host_out))
             log(f"lane {label}: events={summ['events']} "
                 f"completed={summ['completed']} rejected={summ['rejected']} "
                 f"sim_end_time={summ['sim_end_time']} "
                 f"host_reference_s={host_s:.3f} equal=True")
-    return traces
-
-
-def kernel_phase(n_jobs: int, seed: int, want):
-    """Phase 2; returns the runner so the caller can check its mode."""
-    from repro.core.dispatchers import (BestFit, EasyBackfilling, FirstFit,
-                                        FirstInFirstOut)
-    from repro.fleet import FleetRunner
-
-    sims = build_sims(n_jobs, (seed,), lambda: [
-        FirstInFirstOut(FirstFit()), EasyBackfilling(BestFit())])
-    runner = FleetRunner(use_kernel=True)
-    res = runner.run(sims)
-    log_launches(f"kernel-on seed={seed}", res.launches)
-    for i, sim in enumerate(sims):
-        check_equal(f"kernel-on {sim.name} seed={seed} trace", res.trace(i),
-                    want[(seed, sim.name)])
-        log(f"lane kernel-on {sim.name} seed={seed}: "
-            f"events={int(res.finals[i].n_events)} equal_to_kernel_off=True")
-    return runner
 
 
 def host_path_phase(n_jobs: int, out_dir: str):
-    """Phase 3; returns the kernel launches it made, by kernel."""
+    """Phase 2; returns the kernel launches it made, by kernel."""
     from benchmarks.common import seth_jobs
     from repro.configs.seth import SYSTEM
     from repro.core.dispatchers import (BestFit, EasyBackfilling, FirstFit,
@@ -309,7 +284,6 @@ def main() -> None:
     devs = tpu_devices(args.chips)
 
     sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
-    from repro.fleet.engine import default_interpret
     from repro.kernels.ops import kernel_mode
     from repro.utils.compile_cache import enable_compile_cache
 
@@ -317,9 +291,8 @@ def main() -> None:
     t_start = time.perf_counter()
     log(f"device: platform={devs[0].platform} kind={devs[0].device_kind} "
         f"count={len(devs)}")
-    log(f"kernel_mode={kernel_mode()} fleet_interpret={default_interpret()} "
-        f"compile_cache={cache.path}")
-    if kernel_mode() != "tpu" or default_interpret():
+    log(f"kernel_mode={kernel_mode()} compile_cache={cache.path}")
+    if kernel_mode() != "tpu":
         raise AssertionError("kernels would not run compiled on the TPU")
 
     if args.chips == 4:
@@ -327,10 +300,7 @@ def main() -> None:
     else:
         out_dir = os.path.join(ROOT, "results", "chip_smoke")
         shutil.rmtree(out_dir, ignore_errors=True)
-        traces = main_grid(N_JOBS, SEEDS, out_dir, cache)
-        runner = kernel_phase(N_JOBS, SEEDS[0], traces)
-        if runner.interpret:
-            raise AssertionError("kernel-on launch ran the Pallas interpreter")
+        main_grid(N_JOBS, SEEDS, out_dir, cache)
         host_path_phase(HOST_PATH_JOBS, out_dir)
     log(f"total_wall_s={time.perf_counter() - t_start:.3f} "
         f"persistent_cache_hits={cache.hits} "

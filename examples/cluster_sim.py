@@ -1,7 +1,8 @@
 """Cluster fusion demo — the paper's WMS dispatches the ASSIGNED
 architectures onto a 2-pod TPU fleet (DESIGN.md §7):
 
-* job profiles come from the real dry-run records (results/dryrun/),
+* job profiles come from dry-run records under results/dryrun/, which
+  nothing in this repo writes: supply them to run the demo,
 * the fleet sees failures (MTBF model) with checkpoint/restart re-queue,
 * a fault-aware EASY-backfilling dispatcher schedules around them,
 * elastic scaling shrinks deep-queued training jobs into free hosts.
@@ -28,7 +29,8 @@ OUT = "results/cluster_sim"
 def main():
     profiles = load_profiles("results/dryrun", mesh="single")
     if not profiles:
-        sys.exit("run the dry-run first: python -m repro.launch.dryrun")
+        sys.exit("no dry-run job profiles under results/dryrun/; "
+                 "nothing in this repo writes them")
     print(f"loaded {len(profiles)} job profiles from the dry-run")
 
     sys_cfg = tpu_cluster_config(n_pods=2, hosts_per_pod=64)   # 128 hosts

@@ -2,9 +2,9 @@
 
 ``ElasticScaler`` resizes *elastic* jobs (those whose profile has a
 scaling curve) at dispatch time: when the queue is deep it admits jobs at
-reduced chip counts; when the system drains it grows them — the
-checkpoint-reshard path (repro.checkpoint) makes this executable on real
-hardware, here it drives the simulation.
+reduced chip counts; when the system drains it grows them — on real
+hardware a checkpoint-reshard path makes this executable, here it drives
+the simulation.
 
 ``StragglerMonitor`` models slow hosts: hosts with a slowdown factor
 stretch the effective duration of jobs touching them; the monitor detects

@@ -89,7 +89,7 @@ class FailureInjector:
 class CheckpointRestartPolicy:
     """Adjusts a re-queued job so it only re-runs work since the last
     checkpoint (period ``ckpt_every_s``) — the simulation counterpart of
-    ``repro.checkpoint``.  Called by the cluster driver on re-queue."""
+    checkpoint/restart.  Called by the cluster driver on re-queue."""
 
     def __init__(self, ckpt_every_s: int = 600) -> None:
         self.ckpt_every_s = ckpt_every_s

@@ -1,11 +1,12 @@
-"""Architecture job profiles derived from the dry-run roofline records.
+"""Architecture job profiles read from dry-run roofline records.
 
 This closes the loop with the paper's workload generator (§7.3): there,
 job durations come from *synthetic* theoretical FLOPs over per-unit
 performance; here they come from the *compiled artifact* of each
 (arch × shape) cell — FLOPs, HBM bytes and collective bytes measured from
-HLO, turned into a bound step time by the same three-term roofline the
-perf analysis uses.
+HLO, turned into a bound step time by a three-term roofline.  The records
+(``results/dryrun/*.json``) come from outside this repo: nothing in it
+writes them.
 """
 from __future__ import annotations
 

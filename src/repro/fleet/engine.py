@@ -12,15 +12,19 @@ BestFit} (``alloc_code``) — the paper's full Table-2 policy set.
 
 **Scheduling.**  The blocking policies sort queue indices by
 ``(est, queued_time)`` (stable over FIFO arrival order) and stop at the
-first allocation failure; the compiled twin replicates this with a
-three-level lexicographic masked argmin ``(k1, k2, k3)`` re-evaluated per
-start (keys are static within a dispatch round, so the recomputed argmin
-walks exactly the host's priority prefix):
+first allocation failure.  Every key of that order is static — estimates
+never change, ranks follow the fixed ``pending`` order and a row's
+``queued_time`` is its submit time — so :func:`_priority_order` ranks the
+rows once per sim into static positions, and each greedy trip picks the
+next job by one masked argmin over those positions of the queued rows:
 
-    FIFO  (fifo_rank, 0,           0)
-    SJF   (est,       queued_time, fifo_rank)
-    LJF   (-est,      queued_time, fifo_rank)
-    EBF   (fifo_rank, 0,           0)        # FIFO priority
+    FIFO  fifo_rank
+    SJF   lexsort by (est,  queued_time, fifo_rank)
+    LJF   lexsort by (-est, queued_time, fifo_rank)
+    EBF   fifo_rank                              # FIFO priority
+
+A failure drain re-ranks requeued victims, so with failures the order is
+recomputed after each drain.
 
 **EASY-backfilling** extends the round with a ``(shadow_time, extra)``
 carry: when the greedy phase hits its first blocked job (the *head*),
@@ -54,15 +58,6 @@ assignment list order matches the host's busiest-first output.  The
 probe runs under the ``select_nodes`` scope, inside ``dispatch`` or
 ``backfill``.
 
-The fused score+commit step optionally *reuses the ``alloc_score_batch``
-Pallas kernel* (``use_kernel=True``): one ``[M, N]`` fit/score launch per
-dispatch round — the ``BatchProbe`` pattern — with the per-start
-availability recheck ANDed on top.  Every probe pool (greedy avail,
-backfill ``min(avail, extra)``) is ≤ the round-start availability, so
-the live recheck is the binding constraint and traces stay bit-identical;
-the one probe that can EXCEED round-start availability — the head's
-reservation at shadow time — deliberately skips the prefilter.
-
 Everything is int32 (no x64 on the accelerator path); ``INF_I = 2**30``
 is the masked-minimum sentinel.  Termination: every outer iteration
 either advances the submission pointer or retires >= 1 completion, so
@@ -73,7 +68,7 @@ advances the backfill cursor past one queued rank.
 **Phases.**  Each phase runs under a ``jax.named_scope`` (:data:`PHASES`):
 ``prologue`` (the static priority order), then per event ``next_event``
 (event time and the loop test), ``complete``, ``drain`` (failures only),
-``admit``, ``dispatch`` (kernel prefilter, greedy loop), ``backfill``
+``admit``, ``dispatch`` (queued count, greedy loop), ``backfill``
 (shadow walk, head reservation, backfill loop, stats) and ``record``
 (event log, telemetry sample, counters), and ``epilogue`` after the
 loop.  A scope names the HLO ops it emits (``op_name`` metadata, the
@@ -82,7 +77,6 @@ attributes device time to phases.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -90,7 +84,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..kernels.alloc_score import alloc_score_batch_pallas
 from ..kernels.ebf_shadow import shadow_walk
 from .state import (COMPLETED, INF_I, QUEUED, REJECTED, RUNNING, BestFitKey,
                     SimState, UNSET_I, launch_bf_key)
@@ -202,24 +195,20 @@ def _priority_order(s: SimState):
          lambda: rank])                      # EBF runs FIFO priority
 
 
-def _select_nodes(alloc_id, pool, capacity, bf, reqv, need, k_cap, pref,
-                  elig=None):
+def _select_nodes(alloc_id, pool, capacity, bf, reqv, need, k_cap, elig=None):
     """Allocator probe against ``pool`` availability: FirstFit (node-id
     order) or BestFit (busiest-first stable order) via one shared sort
     and cumsum over the policy's node ordering.
 
     Returns ``(ok, sel [N] bool, nodes [K])``; ``bf`` is the machine's
-    :class:`~repro.fleet.state.BestFitKey`; ``pref`` optionally ANDs
-    a precomputed fit prefilter (the per-round kernel launch) into the
-    live fit mask; ``elig`` (bool[N], optional) ANDs the failure-aware
-    node-eligibility mask — the compiled twin of the host's -1
-    availability floor on down/quarantined nodes (DESIGN.md §9).
+    :class:`~repro.fleet.state.BestFitKey`; ``elig`` (bool[N], optional)
+    ANDs the failure-aware node-eligibility mask — the compiled twin of
+    the host's -1 availability floor on down/quarantined nodes
+    (DESIGN.md §9).
     """
     n = pool.shape[0]
     node_ids = jnp.arange(n, dtype=jnp.int32)
     fitn = (pool >= reqv[None, :]).all(axis=1)
-    if pref is not None:
-        fitn = fitn & pref
     if elig is not None:
         fitn = fitn & elig
     with jax.named_scope("select_nodes"):
@@ -245,7 +234,7 @@ def _select_nodes(alloc_id, pool, capacity, bf, reqv, need, k_cap, pref,
 
 
 def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
-                    avail, t, fit_round, pri, q0, elig=None,
+                    avail, t, pri, q0, elig=None,
                     collect_stats=False):
     """One full dispatch round at event time ``t``, in three phases.
 
@@ -303,9 +292,8 @@ def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
         has_cand = q_cnt > 0
         reqv = s.req[idx]
         need = s.n_need[idx]
-        pref = None if fit_round is None else fit_round[idx] > 0
         ok_fit, sel, nodes = _select_nodes(
-            s.alloc_id, avail, s.capacity, bf, reqv, need, k_cap, pref, elig)
+            s.alloc_id, avail, s.capacity, bf, reqv, need, k_cap, elig)
         ok = has_cand & ok_fit
         dec = sel[:, None].astype(jnp.int32) * reqv[None, :]
         avail = jnp.where(ok, avail - dec, avail)
@@ -344,11 +332,10 @@ def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
         found, shadow_t, sh_avail = shadow_walk(avail, rel, assigned, s.req,
                                                 head_req, head_need,
                                                 node_ok=elig)
-        # head reservation at shadow time — shadow availability can exceed
-        # the round-start availability, so NO kernel prefilter
+        # head reservation at shadow time
         _, sel_h, _ = _select_nodes(
             s.alloc_id, sh_avail, s.capacity, bf, head_req, head_need, k_cap,
-            None, elig)
+            elig)
         enter_bf = has_head & found
         extra = jnp.where(
             enter_bf,
@@ -393,12 +380,8 @@ def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
         need = s.n_need[idx]
         before_shadow = before_all[idx]
         pool = jnp.where(before_shadow, avail, pool_b)
-        # kernel prefilter: valid because both pools are <= the
-        # round-start availability, so the live recheck is the binding
-        # constraint — the AND is a consistency fusion
-        pref = None if fit_round is None else fit_round[idx] > 0
         ok_fit, sel, nodes = _select_nodes(
-            s.alloc_id, pool, s.capacity, bf, reqv, need, k_cap, pref, elig)
+            s.alloc_id, pool, s.capacity, bf, reqv, need, k_cap, elig)
         ok = has_cand & ok_fit
 
         dec = sel[:, None].astype(jnp.int32) * reqv[None, :]
@@ -453,15 +436,7 @@ def _dispatch_round(s: SimState, bf: BestFitKey, state, start, end, assigned,
                                   misfit),)
 
 
-def default_interpret() -> bool:
-    """Pallas interpret mode exactly when the backend is not a TPU."""
-    return jax.default_backend() != "tpu"
-
-
-def _advance_impl(s: SimState, bf: BestFitKey, use_kernel: bool,
-                  interpret: Optional[bool]) -> SimState:
-    if interpret is None:
-        interpret = default_interpret()
+def _advance_impl(s: SimState, bf: BestFitKey) -> SimState:
     m = s.submit.shape[0]
     n, r = s.avail.shape
     k_cap = s.assigned.shape[1]
@@ -683,21 +658,16 @@ def _advance_impl(s: SimState, bf: BestFitKey, use_kernel: bool,
             s1 = s._replace(state=state, queued_time=queued_time,
                             fifo_rank=fifo_rank)
 
-        # ---- dispatch (one kernel launch per round) -------------------
+        # ---- dispatch round -------------------------------------------
         # queued count from the admit/start/complete counters (a row is
         # QUEUED iff admitted and neither rejected nor started) — saves
         # an [M] reduction per event
         with jax.named_scope("dispatch"):
             q0 = n_submitted - n_rejected - s.n_started
             any_queued = q0 > 0
-            if use_kernel:
-                fit_round, _ = alloc_score_batch_pallas(
-                    avail, s.capacity, s1.req, interpret=interpret)
-            else:
-                fit_round = None
         res = _dispatch_round(
-            s1, bf, state, s1.start, s1.end, s1.assigned, avail, t, fit_round,
-            pri, q0, elig, collect_stats=has_tele)
+            s1, bf, state, s1.start, s1.end, s1.assigned, avail, t, pri, q0,
+            elig, collect_stats=has_tele)
         (state, start, end, assigned, avail, n_started,
          started_evt) = res[:7]
 
@@ -781,26 +751,21 @@ def _advance_impl(s: SimState, bf: BestFitKey, use_kernel: bool,
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def _advance_jit(state: SimState, bf: BestFitKey, use_kernel: bool,
-                 interpret: Optional[bool]) -> SimState:
-    return _advance_impl(state, bf, use_kernel, interpret)
+_advance_jit = jax.jit(_advance_impl)
 
 
-def advance(state: SimState, use_kernel: bool = False,
-            interpret: Optional[bool] = None) -> SimState:
+def advance(state: SimState) -> SimState:
     """Run one simulation to completion on device; returns the final
     state (all jobs COMPLETED/REJECTED, full event log), with the
-    machine's Best-Fit key (:func:`~repro.fleet.state.launch_bf_key`).
-    ``interpret`` None resolves from the backend
-    (:func:`default_interpret`)."""
+    machine's Best-Fit key (:func:`~repro.fleet.state.launch_bf_key`)."""
     bf = launch_bf_key(np.asarray(state.capacity),
                        int(state.alloc_id) == ALLOC_BF)
-    return _advance_jit(state, bf, use_kernel, interpret)
+    return _advance_jit(state, bf)
 
 
-def advance_fn(use_kernel: bool = False, interpret: Optional[bool] = None):
-    """Unjitted single-sim advance closure ``(state, bf_key) -> state`` —
-    the unit ``FleetRunner`` wraps in ``vmap`` (over the state only) and
-    ``shard_map`` before jitting."""
-    return lambda s, bf: _advance_impl(s, bf, use_kernel, interpret)
+def advance_fn():
+    """Unjitted single-sim advance ``(state, bf_key) -> state`` — the unit
+    ``FleetRunner`` wraps in ``vmap`` (over the state only) and
+    ``shard_map`` before jitting.  A lambda, so the compiled module keeps
+    the name ``jit__lambda`` that the recorded device traces carry."""
+    return lambda s, bf: _advance_impl(s, bf)

@@ -54,7 +54,7 @@ import numpy as np
 from ..telemetry.spans import SpanRecorder
 from ..utils import rss_mb
 from .engine import (ALLOC_BF, ALLOC_NAMES, SCHED_EBF, SCHED_NAMES,
-                     advance_fn, default_interpret)
+                     advance_fn)
 from .state import (COMPLETED, REJECTED, SimMeta, SimState, UNSET_I,
                     bf_key, launch_bf_key)
 
@@ -133,7 +133,6 @@ class FleetResult:
     finals: List[SimState]
     wall_time_s: float            # total batched device wall time
     compile_time_s: float         # 0.0 on a compile-cache hit
-    use_kernel: bool
     n_devices: int = 1
     cache_hit: bool = False       # every launch reused its executable
     # per-launch telemetry when run() split the grid by dispatch cost
@@ -154,7 +153,6 @@ class FleetResult:
             n_events = int(f.n_events)
             n_rounds = int(f.n_rounds)
             per_sim = self.wall_time_s / max(len(self.sims), 1)
-            launches = n_rounds if self.use_kernel else 0
             rss = rss_mb()
             out = {
                 "dispatcher": f"{SCHED_NAMES[sim.sched_id]}-"
@@ -166,9 +164,9 @@ class FleetResult:
                 "cpu_time_s": per_sim,
                 "wall_time_s": per_sim,
                 "dispatch_time_s": per_sim,
-                "kernel_launches": launches,
-                "kernel_launches_per_event": (launches / n_rounds
-                                              if n_rounds else 0.0),
+                # the host Simulator's schema; the fleet launches no kernel
+                "kernel_launches": 0,
+                "kernel_launches_per_event": 0.0,
                 "sim_end_time": int(f.now),
                 "mem_avg_mb": rss,
                 "mem_max_mb": rss,
@@ -355,22 +353,19 @@ class FleetResult:
     def _write_events(self, bench_path: str, i: int, summ: Dict) -> None:
         """Sim ``i``'s event log and summary line, as the host
         simulator's ``{name}-bench.jsonl``.  Only ``t``, ``queue`` and
-        ``running`` vary per line; the rest is one of two tails, ``json.dumps``
-        text formatted once, the second for kernel launches (``use_kernel``
-        and a queue >= 0)."""
+        ``running`` vary per line; the rest is one tail, ``json.dumps``
+        text formatted once."""
         f = self.finals[i]
         n_events = int(f.n_events)
         dispatch_amort = summ["dispatch_time_s"] / max(n_events, 1)
         rss = rss_mb()
-        tails = [json.dumps({"dispatch_s": dispatch_amort,
-                             "kernel_launches": k, "rss_mb": rss})[1:]
-                 for k in (0, int(self.use_kernel))]
-        queue = np.asarray(f.log_queue)[:n_events]
+        tail = json.dumps({"dispatch_s": dispatch_amort,
+                           "kernel_launches": 0, "rss_mb": rss})[1:]
         lines = [f'{{"t": {t}, "queue": {q}, "running": {r}, {tail}\n'
-                 for t, q, r, tail in zip(
-                     np.asarray(f.log_t)[:n_events].tolist(), queue.tolist(),
-                     np.asarray(f.log_running)[:n_events].tolist(),
-                     map(tails.__getitem__, (queue >= 0).tolist()))]
+                 for t, q, r in zip(
+                     np.asarray(f.log_t)[:n_events].tolist(),
+                     np.asarray(f.log_queue)[:n_events].tolist(),
+                     np.asarray(f.log_running)[:n_events].tolist())]
         lines.append(json.dumps({"summary": summ}) + "\n")
         _write_text(bench_path, lines)
 
@@ -408,12 +403,6 @@ class FleetRunner:
 
     Parameters
     ----------
-    use_kernel:
-        Fuse the ``alloc_score_batch`` Pallas kernel into each dispatch
-        round (one launch per round, the BatchProbe pattern).
-    interpret:
-        Pallas interpret mode for the kernel; None resolves from the
-        backend (True exactly off the TPU).
     mesh:
         A 1-D ``Mesh`` with axis ``"sims"`` (see
         :func:`repro.launch.mesh.fleet_mesh`) to shard the sim axis with
@@ -429,7 +418,7 @@ class FleetRunner:
     to a multiple of 16, telemetry sample capacity to a multiple of 64 —
     0 stays 0 in both cases so the specialized engines survive; padding
     is inert, pinned by tests), and the AOT-compiled executable is cached
-    process-wide per ``(batch, M, K, F, S, N, R, T, flags, devices)``
+    process-wide per ``(batch, M, K, F, S, N, R, T, devices, backend)``
     (``T`` the Best-Fit key's length, fixed by the machine), so repeated
     grids of the same rounded-up shape skip the jit entirely
     (``FleetResult.cache_hit``; compile time was ~2.3x the run time of a
@@ -438,15 +427,11 @@ class FleetRunner:
 
     _compile_cache: Dict[Tuple, object] = {}
 
-    def __init__(self, use_kernel: bool = False,
-                 interpret: Optional[bool] = None, mesh=None,
+    def __init__(self, mesh=None,
                  spans: Optional[SpanRecorder] = None) -> None:
         import jax
 
         self._jax = jax
-        self.use_kernel = use_kernel
-        self.interpret = default_interpret() if interpret is None \
-            else interpret
         self.mesh = mesh
         # the launches' spans; each FleetResult records into it too
         self.spans = SpanRecorder() if spans is None else spans
@@ -553,7 +538,7 @@ class FleetRunner:
             })
         return FleetResult(sims=list(sims), finals=finals,
                            wall_time_s=wall, compile_time_s=compile_time,
-                           use_kernel=self.use_kernel, n_devices=n_dev,
+                           n_devices=n_dev,
                            cache_hit=cache_hit, launches=launches,
                            spans=spans)
 
@@ -615,9 +600,7 @@ class FleetRunner:
             mesh = fleet_mesh()
         bf = launch_bf_key(sims[0].state.capacity,
                            any(s.alloc_id == ALLOC_BF for s in sims))
-        fn = jax.vmap(advance_fn(use_kernel=self.use_kernel,
-                                 interpret=self.interpret),
-                      in_axes=(0, None))
+        fn = jax.vmap(advance_fn(), in_axes=(0, None))
         n_sims = len(padded)
         pad_sims = 0
         if mesh is not None:
@@ -636,7 +619,6 @@ class FleetRunner:
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *batch)
 
         n, r = padded[0].avail.shape
-        key = (len(batch), m, k, fev, ts, n, r, bf.rank.shape[0],
-               self.use_kernel, self.interpret, mesh_key,
+        key = (len(batch), m, k, fev, ts, n, r, bf.rank.shape[0], mesh_key,
                jax.default_backend())
         return fn, (stacked, bf), key, n_sims, n_dev

@@ -1,10 +1,10 @@
 """Public jit'd wrappers for the kernel layer.
 
 Dispatch policy: the Pallas path runs compiled on a TPU backend
-(``interpret=False``).  On any other backend — e.g. the CPU-hosted
-multi-pod dry-run — the ``ref.py`` oracles run instead, whose HLO is what
-XLA:TPU would see anyway for these memory-bound ops.
-``REPRO_KERNELS=interpret|ref|stub`` overrides that off the TPU (tests run
+(``interpret=False``).  On any other backend the ``ref.py`` oracles run
+instead, whose HLO is what XLA:TPU would see anyway for these
+memory-bound ops.
+``REPRO_KERNELS=interpret|ref`` overrides that off the TPU (tests run
 the Pallas interpreter on the CPU); on a TPU backend any value but
 ``tpu`` is refused, so a setting can never hide the device.
 """
@@ -13,16 +13,14 @@ from __future__ import annotations
 import os
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .alloc_score import alloc_score_batch_pallas, alloc_score_pallas
 from .ebf_shadow import ebf_shadow_pallas
-from .selective_scan import selective_scan_pallas
 
 
 def kernel_mode() -> str:
-    """``tpu`` (compiled Pallas), ``interpret``, ``ref`` or ``stub``."""
+    """``tpu`` (compiled Pallas), ``interpret`` or ``ref``."""
     forced = os.environ.get("REPRO_KERNELS")
     if jax.default_backend() == "tpu":
         if forced not in (None, "", "tpu"):
@@ -30,7 +28,7 @@ def kernel_mode() -> str:
                 f"REPRO_KERNELS={forced} would run the kernels off the "
                 f"TPU this process holds; unset it to run them compiled")
         return "tpu"
-    if forced in ("interpret", "ref", "tpu", "stub"):
+    if forced in ("interpret", "ref", "tpu"):
         return forced
     return "ref"
 
@@ -43,24 +41,6 @@ def kernel_mode() -> str:
 # launches per dispatch event.
 # ----------------------------------------------------------------------
 from .counters import launch_count, launch_stats, record as _record
-
-
-def _scan_traffic_stub(u, delta, A, B, C, D):
-    """HBM-traffic-equivalent stand-in for the Pallas selective-scan.
-
-    Used ONLY for dry-run lowering (REPRO_KERNELS=stub): one streaming
-    pass over u/delta/B/C -> y, mirroring the kernel's BlockSpec-implied
-    HBM traffic (the SSM state lives in VMEM scratch and never touches
-    HBM — the whole point of the kernel, DESIGN.md §2).  The recurrence's
-    FLOPs (~Di·S·10 per token, <1% of the block matmuls) are intentionally
-    approximated; numerics are NOT equivalent — never use outside lowering.
-    """
-    import jax.numpy as jnp
-    mix = (B * C).sum(-1)[..., None]                         # [Bt, L, 1]
-    y = u * jax.nn.silu(delta) + u * mix + D[None, None, :]
-    h_last = jnp.zeros((u.shape[0], u.shape[2], A.shape[1]),
-                       jnp.float32) + A.sum() * 0.0
-    return y.astype(jnp.float32), h_last
 
 
 def alloc_score(avail, capacity, req):
@@ -128,47 +108,3 @@ def ebf_shadow_fits(avail, deltas, req):
         fits = ebf_shadow_pallas(avail, deltas, req,
                                  interpret=(mode == "interpret"))
     return np.asarray(fits)[:m]
-
-
-def selective_scan(u, delta, A, B, C, D, chunk: int = 128):
-    """Mamba-1 selective scan: (y, h_last)."""
-    _record("selective_scan")
-    mode = kernel_mode()
-    if mode == "stub":
-        return _scan_traffic_stub(u, delta, A, B, C, D)
-    if mode == "ref":
-        return ref.selective_scan_ref(u, delta, A, B, C, D)
-    L, di = u.shape[1], u.shape[2]
-    chunk = min(chunk, L)
-    while chunk > 4 and L % chunk:
-        chunk //= 2
-    block_d = 512
-    while block_d > 4 and di % block_d:
-        block_d //= 2
-    if L % chunk or di % block_d:      # irregular shapes: oracle path
-        return ref.selective_scan_ref(u, delta, A, B, C, D)
-    return _scan_with_ref_grad(u, delta, A, B, C, D, chunk, block_d,
-                               interpret=(mode == "interpret"))
-
-
-def _scan_with_ref_grad(u, delta, A, B, C, D, chunk, block_d, interpret):
-    """Pallas forward + ref-oracle backward (pallas_call has no built-in
-    AD; a production deployment would pair this with a handwritten
-    backward kernel — the ref VJP is the correctness-preserving default)."""
-
-    @jax.custom_vjp
-    def f(u, delta, A, B, C, D):
-        return selective_scan_pallas(u, delta, A, B, C, D, chunk=chunk,
-                                     block_d=block_d, interpret=interpret)
-
-    def fwd(u, delta, A, B, C, D):
-        out = selective_scan_pallas(u, delta, A, B, C, D, chunk=chunk,
-                                    block_d=block_d, interpret=interpret)
-        return out, (u, delta, A, B, C, D)
-
-    def bwd(res, ct):
-        _, vjp = jax.vjp(ref.selective_scan_ref, *res)
-        return vjp(ct)
-
-    f.defvjp(fwd, bwd)
-    return f(u, delta, A, B, C, D)

@@ -2,8 +2,8 @@
 
 These are the semantic ground truth: each kernel's test sweeps shapes and
 dtypes and asserts allclose against these functions.  They are also the
-portable fallback used when lowering for a non-TPU backend (e.g. the
-CPU-hosted multi-pod dry-run), so they must be jit-friendly.
+portable fallback used when lowering for a non-TPU backend, so they must
+be jit-friendly.
 """
 from __future__ import annotations
 
@@ -69,37 +69,3 @@ def ebf_shadow_ref(avail: jax.Array, deltas: jax.Array, req: jax.Array):
     cum = avail[None, :, :] + jnp.cumsum(deltas, axis=0)   # [M, N, R]
     fit = jnp.all(cum >= req[None, None, :], axis=2)       # [M, N]
     return fit.sum(axis=1).astype(jnp.int32)
-
-
-# ----------------------------------------------------------------------
-# selective_scan: Mamba-1 diagonal SSM recurrence
-# ----------------------------------------------------------------------
-def selective_scan_ref(u, delta, A, B, C, D, h0=None):
-    """Sequential oracle of the selective scan.
-
-    u, delta: f32[Bt, L, Di]; A: f32[Di, S]; B, C: f32[Bt, L, S];
-    D: f32[Di].  Returns (y f32[Bt, L, Di], h_last f32[Bt, Di, S]).
-
-    Recurrence (ZOH discretization, diagonal A):
-        dA_t = exp(delta_t[:, None] * A)            [Di, S]
-        dB_t = delta_t[:, None] * B_t[None, :]      [Di, S]
-        h_t  = dA_t * h_{t-1} + dB_t * u_t[:, None]
-        y_t  = (h_t @ C_t) + D * u_t
-    """
-    Bt, L, Di = u.shape
-    S = A.shape[1]
-    if h0 is None:
-        h0 = jnp.zeros((Bt, Di, S), dtype=jnp.float32)
-
-    def step(h, xs):
-        u_t, d_t, B_t, C_t = xs          # [Bt,Di], [Bt,Di], [Bt,S], [Bt,S]
-        dA = jnp.exp(d_t[..., None] * A[None, :, :])          # [Bt, Di, S]
-        dB = d_t[..., None] * B_t[:, None, :]                 # [Bt, Di, S]
-        h = dA * h + dB * u_t[..., None]
-        y = jnp.einsum("bds,bs->bd", h, C_t) + D[None, :] * u_t
-        return h, y
-
-    xs = (jnp.moveaxis(u, 1, 0), jnp.moveaxis(delta, 1, 0),
-          jnp.moveaxis(B, 1, 0), jnp.moveaxis(C, 1, 0))
-    h_last, ys = jax.lax.scan(step, h0, xs)
-    return jnp.moveaxis(ys, 0, 1), h_last

@@ -1,44 +1,15 @@
-"""Production mesh definitions.
+"""The fleet's device mesh, and the published peaks of the chips it runs on.
 
-``make_production_mesh`` is a FUNCTION (not a module constant) so that
-importing this module never touches jax device state — the dry-run driver
-sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any
-jax initialization; everything else (smoke tests, benches) sees 1 device.
-
-Topology model: TPU v5e pods — a pod is a 16×16 slice (256 chips); the
-multi-pod mesh stacks 2 pods on a leading ``pod`` axis (data-parallel
-across pods, as inter-pod DCI bandwidth ≪ intra-pod ICI).
-
-Every mesh uses ``Auto`` axes: the sharding rules place arrays with
-``with_sharding_constraint``, which ``Explicit`` axes (the
-``jax.make_mesh`` default) refuse.
+The mesh is built by a FUNCTION (not a module constant), so importing
+this module never touches jax device state.  It uses ``Auto`` axes, not
+the ``Explicit`` axes that ``jax.make_mesh`` defaults to.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 import jax
 from jax.sharding import AxisType, Mesh
-
-
-def _mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
-def make_debug_mesh(n_data: int = 2, n_model: int = 2,
-                    pods: Optional[int] = None) -> Mesh:
-    """Small mesh for CI-scale sharding tests (requires host device count
-    >= product, set via XLA_FLAGS in the spawning process)."""
-    if pods:
-        return _mesh((pods, n_data, n_model), ("pod", "data", "model"))
-    return _mesh((n_data, n_model), ("data", "model"))
 
 
 def fleet_mesh(n_sims: Optional[int] = None) -> Mesh:
@@ -51,7 +22,7 @@ def fleet_mesh(n_sims: Optional[int] = None) -> Mesh:
     mesh to the first ``n_sims`` devices (must divide the batch).
     """
     n = n_sims or len(jax.devices())
-    return _mesh((n,), ("sims",))
+    return jax.make_mesh((n,), ("sims",), axis_types=(AxisType.Auto,))
 
 
 # Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:

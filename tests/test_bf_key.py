@@ -223,7 +223,7 @@ def test_select_nodes_takes_the_first_fitting_in_policy_order(alloc_id,
     n, k_cap = capacity.shape[0], 4
     rng = np.random.default_rng(7)
     probe = jax.jit(lambda a, p, k, r, need, e: _select_nodes(
-        a, p, jnp.asarray(capacity), k, r, need, k_cap, None, e))
+        a, p, jnp.asarray(capacity), k, r, need, k_cap, e))
     for _ in range(40):
         # few distinct usage levels, so loads tie often
         pool = capacity - capacity * rng.integers(0, 3, capacity.shape) // 2

@@ -149,26 +149,3 @@ def test_plot_factory_group_validation(tmp_path):
     with pytest.raises(ValueError):
         pf.produce_plot("dispatch_time")   # performance plot, wrong group
 
-
-def test_hlo_analyzer_known_costs():
-    """The scan-corrected analyzer must reproduce hand-computable costs
-    (the foundation of §Roofline)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.launch.hlo_analysis import analyze_hlo_text
-
-    M, N, K, L = 64, 96, 32, 5
-    txt = jax.jit(lambda a, b: a @ b).lower(
-        jax.ShapeDtypeStruct((M, K), jnp.float32),
-        jax.ShapeDtypeStruct((K, N), jnp.float32)).compile().as_text()
-    t = analyze_hlo_text(txt)
-    assert abs(t.flops - 2 * M * N * K) / (2 * M * N * K) < 0.02
-
-    def step(c, w):
-        return c @ w, ()
-    txt = jax.jit(lambda c, ws: jax.lax.scan(step, c, ws)[0]).lower(
-        jax.ShapeDtypeStruct((M, M), jnp.float32),
-        jax.ShapeDtypeStruct((L, M, M), jnp.float32)).compile().as_text()
-    t = analyze_hlo_text(txt)
-    exp = 2 * M * M * M * L
-    assert abs(t.flops - exp) / exp < 0.02, "while trip-count correction"

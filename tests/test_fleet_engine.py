@@ -120,23 +120,6 @@ def test_fleet_outputs_feed_metrics_pipeline(fleet_result, tmp_path):
     assert metrics.dispatch_time_by_queue_size(bench)
 
 
-# ----------------------------------------------------------------------
-@pytest.mark.parametrize("sc,ac", [(SCHED_SJF, ALLOC_FF),
-                                   (SCHED_EBF, ALLOC_BF)])
-def test_kernel_path_is_decision_identical(sc, ac):
-    """use_kernel=True routes scoring through the Pallas batch-probe
-    kernel; every dispatch decision must be unchanged — including EBF,
-    whose head reservation deliberately bypasses the prefilter."""
-    sims = lambda: [FleetRunner.build("k", _workload(150, 7), SYS, sc,
-                                      alloc_id=ac,
-                                      job_factory=JobFactory())]
-    plain = FleetRunner(use_kernel=False).run(sims())
-    kernel = FleetRunner(use_kernel=True).run(sims())
-    assert kernel.trace(0) == plain.trace(0)
-    assert kernel.summary(0)["kernel_launches"] > 0
-    assert plain.summary(0)["kernel_launches"] == 0
-
-
 @pytest.mark.parametrize("sched,sc,ac", [
     (lambda: LongestJobFirst(FirstFit()), SCHED_LJF, ALLOC_FF),
     (lambda: ShortestJobFirst(BestFit()), SCHED_SJF, ALLOC_BF),
@@ -227,8 +210,7 @@ def test_midsim_snapshot_continues_identically(tmp_path):
                                               sched_id=SCHED_FIFO)
     result = FleetResult(
         sims=[FleetSim("cut", state, meta, SCHED_FIFO)],
-        finals=[advance(state)], wall_time_s=0.0, compile_time_s=0.0,
-        use_kernel=False)
+        finals=[advance(state)], wall_time_s=0.0, compile_time_s=0.0)
     got = result.trace(0)
     assert got, "snapshot carried no live jobs"
     want = _host_trace(FirstInFirstOut(FirstFit()), tmp_path, n, seed)

@@ -4,9 +4,8 @@
 the oracle here is the plain writer it replaced: ``json.dumps`` of each
 ``records(i)`` entry for ``{name}-output.jsonl``, and ``json.dumps`` of each
 event's dict plus the summary line for ``{name}-bench.jsonl``.  Cases cover
-both dispatch cost classes, rejected jobs, ids that JSON escapes, the
-kernel path (``kernel_launches`` per line), a failure schedule (the
-summary's ``failures`` block) and device telemetry.
+both dispatch cost classes, rejected jobs, ids that JSON escapes, a
+failure schedule (the summary's ``failures`` block) and device telemetry.
 """
 import dataclasses
 import json
@@ -58,16 +57,15 @@ def _injector():
 
 
 CASES = {
-    "fifo-ff": (_workload, SCHED_FIFO, ALLOC_FF, {}, False),
-    "ebf-bf": (_workload, SCHED_EBF, ALLOC_BF, {}, False),
-    "rejected": (_with_rejects, SCHED_EBF, ALLOC_FF, {}, False),
-    "escaped-ids": (_with_escaped_ids, SCHED_FIFO, ALLOC_BF, {}, False),
-    "kernel": (_workload, SCHED_EBF, ALLOC_BF, {}, True),
+    "fifo-ff": (_workload, SCHED_FIFO, ALLOC_FF, {}),
+    "ebf-bf": (_workload, SCHED_EBF, ALLOC_BF, {}),
+    "rejected": (_with_rejects, SCHED_EBF, ALLOC_FF, {}),
+    "escaped-ids": (_with_escaped_ids, SCHED_FIFO, ALLOC_BF, {}),
     "failures": (_workload, SCHED_FIFO, ALLOC_FF,
                  dict(failures=_injector(), quarantine_s=1800,
-                      ckpt_every_s=600), False),
+                      ckpt_every_s=600)),
     "telemetry": (_workload, SCHED_EBF, ALLOC_FF,
-                  dict(telemetry_stride=7), False),
+                  dict(telemetry_stride=7)),
 }
 
 
@@ -80,16 +78,14 @@ def _old_bench_file(result, i):
     f = result.finals[i]
     n_events = int(f.n_events)
     dispatch_amort = summ["dispatch_time_s"] / max(n_events, 1)
-    log_q = np.asarray(f.log_queue)
     out = []
     for e in range(n_events):
         out.append(json.dumps({
             "t": int(np.asarray(f.log_t)[e]),
-            "queue": int(log_q[e]),
+            "queue": int(np.asarray(f.log_queue)[e]),
             "running": int(np.asarray(f.log_running)[e]),
             "dispatch_s": dispatch_amort,
-            "kernel_launches": 1 if (result.use_kernel and log_q[e] >= 0)
-                               else 0,
+            "kernel_launches": 0,
             "rss_mb": RSS_MB,
         }).encode() + b"\n")
     out.append(json.dumps({"summary": summ}).encode() + b"\n")
@@ -98,18 +94,12 @@ def _old_bench_file(result, i):
 
 @pytest.fixture(scope="module")
 def results():
-    """One launch of every kernel-off case, and one of the kernel case."""
-    plain = [k for k, c in CASES.items() if not c[4]]
-    out = {}
-    for use_kernel, names in ((False, plain),
-                              (True, [k for k in CASES if k not in plain])):
-        sims = [FleetRunner.build(name, CASES[name][0](), SYS, CASES[name][1],
-                                  alloc_id=CASES[name][2],
-                                  job_factory=JobFactory(), **CASES[name][3])
-                for name in names]
-        res = FleetRunner(use_kernel=use_kernel).run(sims)
-        out.update({name: (res, i) for i, name in enumerate(names)})
-    return out
+    """Every case, in one ``run``."""
+    sims = [FleetRunner.build(name, make(), SYS, sc, alloc_id=ac,
+                              job_factory=JobFactory(), **kw)
+            for name, (make, sc, ac, kw) in CASES.items()]
+    res = FleetRunner().run(sims)
+    return {name: (res, i) for i, name in enumerate(CASES)}
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -131,8 +121,6 @@ def test_cases_reach_what_they_name(results):
     assert any(r["resources"] == {"core": 2} for r in recs["rejected"])
     assert {'q"uote', "back\\slash", "café"} <= {
         r["id"] for r in recs["escaped-ids"]}
-    kernel, i = results["kernel"]
-    assert kernel.use_kernel and kernel.summary(i)["kernel_launches"] > 0
     assert "failures" in results["failures"][0].summary(results["failures"][1])
     assert "telemetry" in results["telemetry"][0].summary(
         results["telemetry"][1])
@@ -140,11 +128,10 @@ def test_cases_reach_what_they_name(results):
 
 def test_kernel_launches_follow_the_queue_per_line(results, tmp_path,
                                                   monkeypatch):
-    """The engine logs no negative queue, so the kernel lane's lines all
-    read 1; a log with negative entries takes the other tail on exactly
-    those lines, as the oracle does."""
+    """The fleet launches no kernel, so every line reads 0, on a log with
+    negative queue entries too, as the oracle does."""
     monkeypatch.setattr(runner_mod, "rss_mb", lambda: RSS_MB)
-    result, i = results["kernel"]
+    result, i = results["ebf-bf"]
     f = result.finals[i]
     queue = np.asarray(f.log_queue).copy()
     queue[1:int(f.n_events):3] = -1
@@ -156,7 +143,7 @@ def test_kernel_launches_follow_the_queue_per_line(results, tmp_path,
     assert written == _old_bench_file(result, 0)
     launches = [json.loads(ln).get("kernel_launches")
                 for ln in written.splitlines()[:-1]]
-    assert set(launches) == {0, 1}
+    assert set(launches) == {0}
 
 
 @pytest.mark.parametrize("case", ["fifo-ff", "rejected"])

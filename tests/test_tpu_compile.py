@@ -1,5 +1,5 @@
-"""Compile the main path's kernels and the fleet engine for a described
-TPU v5e chip — no chip attached.
+"""Compile the host vectorized path's Pallas kernels and the fleet engine
+for a described TPU v5e chip — no chip attached.
 
 Interpret-mode tests cannot see what the TPU compiler refuses (an
 in-kernel ``cumsum``, a block that is not (8, 128)-aligned, too much
@@ -105,13 +105,10 @@ def bf_key_shapes(one_chip):
     return jax.tree.map(lambda x: _spec(x.shape, one_chip, x.dtype), key)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_fleet_advance_compiles(lane_shapes, bf_key_shapes, use_kernel):
+def test_fleet_advance_compiles(lane_shapes, bf_key_shapes):
     from repro.fleet.engine import advance_fn
 
-    # interpret=False explicitly: this process's backend is the CPU, so
-    # the default would resolve to the Pallas interpreter
-    fn = jax.vmap(advance_fn(use_kernel=use_kernel, interpret=False),
-                  in_axes=(0, None))
+    fn = jax.vmap(advance_fn(), in_axes=(0, None))
     txt = _compile(fn, lane_shapes, bf_key_shapes)
-    assert ("tpu_custom_call" in txt) == use_kernel
+    # the engine is plain XLA: no Pallas kernel in the loop
+    assert "tpu_custom_call" not in txt
